@@ -13,7 +13,8 @@ import tempfile
 import jax
 
 from repro.configs import get_smoke
-from repro.launch.mesh import make_local_mesh
+from repro.launch.compile_cache import use_compile_cache
+from repro.dist.mesh import make_local_mesh
 from repro.models import init_params
 from repro.train import checkpoint as ckpt
 from repro.train.elastic import elastic_restore, shard_targets
@@ -21,6 +22,7 @@ from repro.train.optimizer import OptConfig, init_opt_state
 
 
 def main() -> None:
+    use_compile_cache()
     cfg = get_smoke("granite-3-2b")
     oc = OptConfig()
     params = init_params(cfg, jax.random.PRNGKey(0))

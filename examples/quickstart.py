@@ -13,6 +13,9 @@ from repro.core import (FCFSScheduler, HPC_CLUSTER, LocalityScheduler,
                         ProactiveScheduler, TaskGraph, WorkflowExecutor,
                         compile_workflow, simulate, size_hint, task)
 from repro.core.workloads import fig2_workflow
+from repro.launch.compile_cache import use_compile_cache
+
+use_compile_cache()
 
 # --- 1. the compiler layer: a hinted DAG (the paper's @ annotations) --------
 g = TaskGraph()

@@ -15,11 +15,13 @@ import numpy as np
 
 from repro.configs import get_smoke
 from repro.core.locstore import LocStore
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import init_params
 from repro.serve.engine import Router, ServingEngine
 
 
 def main() -> None:
+    use_compile_cache()
     cfg = dataclasses.replace(get_smoke("granite-3-2b"), dtype="float32")
     params = init_params(cfg, jax.random.PRNGKey(0))
     store = LocStore(2)

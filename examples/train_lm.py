@@ -12,6 +12,7 @@ import argparse
 import tempfile
 
 from repro.configs.base import ModelConfig
+from repro.launch.compile_cache import use_compile_cache
 from repro.train.loop import TrainConfig, train
 from repro.train.optimizer import OptConfig
 
@@ -31,6 +32,7 @@ def main() -> None:
     ap.add_argument("--fail-at", type=int, default=150,
                     help="simulate a node failure at this step (0 = off)")
     args = ap.parse_args()
+    use_compile_cache()
 
     n_params = sum(x.size for x in __import__("jax").tree.leaves(
         __import__("jax").eval_shape(

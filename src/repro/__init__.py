@@ -8,7 +8,3 @@ Layer map (see README.md):
   serve    decode engine
   launch   meshes, input specs, dry-run lowering of every (arch×shape) cell
 """
-
-from repro import _compat
-
-_compat.install()

@@ -49,6 +49,7 @@ class PrefetchEngine:
         self.submitted = 0
         self.completed = 0
         self.skipped_read_once = 0
+        self.device_puts = 0
         self.bytes_prefetched = 0.0
         # failure hygiene: a dead node's in-flight handles, device copies and
         # pin records describe replicas that no longer exist — purge them on
@@ -151,15 +152,13 @@ class PrefetchEngine:
                 self.skipped_read_once += 1
             return value
         if tier == "hbm" and self.device_of is not None:
-            try:
+            dev = self.device_of(dst)
+            if dev is not None:
                 import jax
-                dev = self.device_of(dst)
-                if dev is not None:
-                    value = jax.device_put(value, dev)  # async dispatch
-                    with self._lock:
-                        self._device_copies[(name, dst)] = value
-            except Exception:
-                pass  # host-level replication still proceeds
+                value = jax.device_put(value, dev)  # async dispatch
+                with self._lock:
+                    self._device_copies[(name, dst)] = value
+                    self.device_puts += 1
         placement = self.store.replicate(name, [dst], tier=tier)
         with self._lock:
             self.completed += 1
@@ -198,5 +197,6 @@ class PrefetchEngine:
         return {"submitted": float(self.submitted),
                 "completed": float(self.completed),
                 "skipped_read_once": float(self.skipped_read_once),
+                "device_puts": float(self.device_puts),
                 "pins_held": float(pins),
                 "bytes_prefetched": self.bytes_prefetched}

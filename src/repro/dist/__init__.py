@@ -9,8 +9,9 @@ device placement:
   hints        ``sharding_rules(mesh)`` context + ``hint(x, *roles)`` — the
                lazy in-model annotation hook every layer calls
   compression  int8 error-feedback gradient compression for DP collectives
+  mesh         ``Auto``-axis (data, model) meshes over the local devices
 """
 
-from repro.dist import compression, hints, sharding
+from repro.dist import compression, hints, mesh, sharding
 
-__all__ = ["compression", "hints", "sharding"]
+__all__ = ["compression", "hints", "mesh", "sharding"]

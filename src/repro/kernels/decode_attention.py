@@ -5,8 +5,11 @@ S cache entries. Memory-bound by design (roofline: ~2·S·hd bytes of cache per
 head at ~0 reuse), so the kernel's job is to stream k/v blocks through VMEM at
 full HBM bandwidth while keeping the softmax state in registers/VMEM.
 
-Grid = (B, Hq, S/BK) — the cache sweep is the sequential dim; online-softmax
-state (m, l, acc) persists in VMEM scratch. Per-batch ``lengths`` masks unseen
+Grid = (B, Hkv, S/BK) — the cache sweep is the sequential dim; each step
+scores a kv head's whole query group against one cache block as a
+(group, hd) x (hd, BK) matmul, with the caches laid out head-major so the
+blocks tile as the TPU requires. Online-softmax state (m, l, acc) persists
+in VMEM scratch. Per-batch ``lengths`` masks unseen
 cache slots; sliding-window archs pass ``window`` so dead blocks are skipped
 with pl.when (compute-free predication — on real TPUs the bandwidth win comes
 from shrinking the swept region; see ops.window_slice below).
@@ -26,7 +29,7 @@ DEFAULT_BK = 512
 
 
 def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
-                   *, scale: float, window: int, bk: int):
+                   *, scale: float, window: int, bk: int, group: int):
     ik = pl.program_id(2)
     nk = pl.num_programs(2)
 
@@ -37,7 +40,7 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     length = len_ref[pl.program_id(0)]                  # this batch's valid entries
-    k_pos = ik * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)[0]
+    k_pos = ik * bk + jax.lax.broadcasted_iota(jnp.int32, (group, bk), 1)
 
     live = (ik * bk) < length
     if window > 0:
@@ -45,26 +48,28 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
 
     @pl.when(live)
     def _compute():
-        q = q_ref[0, 0, :].astype(jnp.float32)          # (hd,)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)       # (bk, hd)
-        s = (k @ q) * scale                             # (bk,)
+        q = q_ref[...].astype(jnp.float32)              # (group, hd)
+        k = k_ref[...].astype(jnp.float32)              # (bk, hd)
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
         ok = k_pos < length
         if window > 0:
             ok &= (length - 1 - k_pos) < window
-        s = jnp.where(ok, s, NEG_INF)
-        m_prev = m_scr[0, 0]
-        m_cur = jnp.maximum(m_prev, s.max())
+        s = jnp.where(ok, s, NEG_INF)                   # (group, bk)
+        m_prev = m_scr[...]                             # (group, 1)
+        m_cur = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_cur)
-        p = jnp.where(ok, jnp.exp(s - m_cur), 0.0)      # (bk,)
-        l_scr[0, 0] = l_scr[0, 0] * alpha + p.sum()
-        v = v_ref[0, :, 0, :].astype(jnp.float32)       # (bk, hd)
-        acc_scr[0, :] = acc_scr[0, :] * alpha + p @ v
-        m_scr[0, 0] = m_cur
+        p = jnp.where(ok, jnp.exp(s - m_cur), 0.0)
+        l_scr[...] = l_scr[...] * alpha + p.sum(axis=1, keepdims=True)
+        v = v_ref[...].astype(jnp.float32)              # (bk, hd)
+        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        m_scr[...] = m_cur
 
     @pl.when(ik == nk - 1)
     def _finalize():
-        o_ref[0, 0, :] = (acc_scr[0, :]
-                          / jnp.maximum(l_scr[0, 0], 1e-30)).astype(o_ref.dtype)
+        o_ref[...] = (acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
+                      ).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -88,36 +93,35 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     bk = min(block_k, max(S, 8))
     s_pad = (-S) % bk
     hd_pad = (-hd) % 128
-    if hd_pad:
-        q = jnp.pad(q, ((0, 0), (0, 0), (0, hd_pad)))
-    if s_pad or hd_pad:
-        k_cache = jnp.pad(k_cache, ((0, 0), (0, s_pad), (0, 0), (0, hd_pad)))
-        v_cache = jnp.pad(v_cache, ((0, 0), (0, s_pad), (0, 0), (0, hd_pad)))
+    # head-major: one block per kv head holds its whole query group, so the
+    # last two block dims are (group, hd) and (bk, hd) — whole or tiled
+    q = jnp.pad(q, ((0, 0), (0, 0), (0, hd_pad))).reshape(B, Hkv, group, -1)
+    k_cache, v_cache = (
+        jnp.pad(c, ((0, 0), (0, s_pad), (0, 0), (0, hd_pad))
+                ).transpose(0, 2, 1, 3) for c in (k_cache, v_cache))
     Sp, hdp = S + s_pad, hd + hd_pad
 
-    grid = (B, Hq, Sp // bk)
+    grid = (B, Hkv, Sp // bk)
     kernel = functools.partial(_decode_kernel, scale=scale, window=window,
-                               bk=bk)
+                               bk=bk, group=group)
+    q_spec = pl.BlockSpec((None, None, group, hdp), lambda b, h, ik: (b, h, 0, 0))
+    kv_spec = pl.BlockSpec((None, None, bk, hdp), lambda b, h, ik: (b, h, ik, 0))
     out = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),   # lengths, whole array
-            pl.BlockSpec((1, 1, hdp), lambda b, h, ik: (b, h, 0)),
-            pl.BlockSpec((1, bk, 1, hdp),
-                         lambda b, h, ik, g=group: (b, ik, h // g, 0)),
-            pl.BlockSpec((1, bk, 1, hdp),
-                         lambda b, h, ik, g=group: (b, ik, h // g, 0)),
+            q_spec, kv_spec, kv_spec,
         ],
-        out_specs=pl.BlockSpec((1, 1, hdp), lambda b, h, ik: (b, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Hq, hdp), q.dtype),
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, group, hdp), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, hdp), jnp.float32),
+            pltpu.VMEM((group, 1), jnp.float32),     # running max
+            pltpu.VMEM((group, 1), jnp.float32),     # running denom
+            pltpu.VMEM((group, hdp), jnp.float32),   # running accumulator
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(lengths.astype(jnp.int32), q, k_cache, v_cache)
-    return out[:, :, :hd]
+    return out.reshape(B, Hq, hdp)[:, :, :hd]

@@ -1,6 +1,10 @@
 """Blockwise fused attention (flash) — Pallas TPU kernel.
 
 TPU-native design (not a CUDA port):
+  * q/k/v are laid out head-major, (B, H, S, hd), so every block is one
+    head's (rows, hd) tile — the last two block dims are then (8k, 128k) as
+    the TPU's tiling requires (a head axis of block 1 in the second-minor
+    position is refused by the chip's compiler).
   * grid = (B, Hq, Sq/BQ, Sk/BK); the LAST grid dim is sequential on TPU, so
     the online-softmax running state (m, l, acc) lives in VMEM scratch and
     persists across the k-block sweep — no atomics, no shared-memory tiling.
@@ -47,44 +51,40 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
     q_pos = q_offset + iq * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
     k_pos = ik * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
 
-    # static block-level skip bound: last k position possibly visible
-    def block_live() -> bool | jax.Array:
-        live = k_pos[0, 0] < sk_valid                 # any valid key at all
-        if causal:
-            live &= (ik * bk) <= (q_offset + iq * bq + bq - 1)
-        if window > 0:
-            live &= (ik * bk + bk - 1) >= (q_offset + iq * bq - window + 1)
-        return live
+    # block-level skip: does this (q-block, k-block) pair hold any live key?
+    live = (ik * bk) < sk_valid
+    if causal:
+        live &= (ik * bk) <= (q_offset + iq * bq + bq - 1)
+    if window > 0:
+        live &= (ik * bk + bk - 1) >= (q_offset + iq * bq - window + 1)
 
-    @pl.when(block_live())
+    @pl.when(live)
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)     # (bq, hd)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)     # (bk, hd)
+        q = q_ref[...].astype(jnp.float32)            # (bq, hd)
+        k = k_ref[...].astype(jnp.float32)            # (bk, hd)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
-        ok = k_pos < sk_valid
-        ok &= (q_pos < q_offset + sq_valid)
+        ok = (k_pos < sk_valid) & (q_pos < q_offset + sq_valid)
         if causal:
             ok &= k_pos <= q_pos
         if window > 0:
             ok &= (q_pos - k_pos) < window
         s = jnp.where(ok, s, NEG_INF)
 
-        m_prev = m_scr[:, 0]                          # (bq,)
-        m_cur = jnp.maximum(m_prev, s.max(axis=1))
+        m_prev = m_scr[...]                           # (bq, 1)
+        m_cur = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_cur)
-        p = jnp.exp(s - m_cur[:, None])
-        p = jnp.where(ok, p, 0.0)
-        l_scr[:, 0] = l_scr[:, 0] * alpha + p.sum(axis=1)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)     # (bk, hd)
-        acc_scr[...] = acc_scr[...] * alpha[:, None] + jax.lax.dot_general(
+        p = jnp.where(ok, jnp.exp(s - m_cur), 0.0)
+        l_scr[...] = l_scr[...] * alpha + p.sum(axis=1, keepdims=True)
+        v = v_ref[...].astype(jnp.float32)            # (bk, hd)
+        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-        m_scr[:, 0] = m_cur
+        m_scr[...] = m_cur
 
     @pl.when(ik == nk - 1)
     def _finalize():
-        denom = jnp.maximum(l_scr[:, 0], 1e-30)[:, None]
-        o_ref[0, :, 0, :] = (acc_scr[...] / denom).astype(o_ref.dtype)
+        o_ref[...] = (acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
+                      ).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -98,6 +98,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     interpret: bool = False) -> jax.Array:
     """q: (B, Sq, Hq, hd); k/v: (B, Sk, Hkv, hd). Returns (B, Sq, Hq, hd).
 
+    The kernel sees head-major copies, (B, H, S, hd): a block is then one
+    head's (rows, hd) tile, whose last two dims the TPU tiles as (8k, 128k).
     Pads Sq/Sk to block multiples and hd to a multiple of 128 (MXU lane
     width); padded keys are masked, padded queries discarded on slice-out.
     """
@@ -112,31 +114,29 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     sq_pad = (-Sq) % bq
     sk_pad = (-Sk) % bk
     hd_pad = (-hd) % 128
-    if sq_pad or hd_pad:
-        q = jnp.pad(q, ((0, 0), (0, sq_pad), (0, 0), (0, hd_pad)))
-    if sk_pad or hd_pad:
-        k = jnp.pad(k, ((0, 0), (0, sk_pad), (0, 0), (0, hd_pad)))
-        v = jnp.pad(v, ((0, 0), (0, sk_pad), (0, 0), (0, hd_pad)))
+
+    def head_major(x, s_pad):
+        return jnp.pad(x, ((0, 0), (0, s_pad), (0, 0), (0, hd_pad))
+                       ).transpose(0, 2, 1, 3)
+
+    q, k, v = head_major(q, sq_pad), head_major(k, sk_pad), head_major(v, sk_pad)
     Sqp, Skp, hdp = Sq + sq_pad, Sk + sk_pad, hd + hd_pad
 
     grid = (B, Hq, Sqp // bq, Skp // bk)
     kernel = functools.partial(
         _flash_kernel, scale=scale, causal=causal, window=window,
         q_offset=q_offset, sq_valid=Sq, sk_valid=Sk, bq=bq, bk=bk)
+    q_spec = pl.BlockSpec((None, None, bq, hdp),
+                          lambda b, h, iq, ik: (b, h, iq, 0))
+    kv_spec = pl.BlockSpec((None, None, bk, hdp),
+                           lambda b, h, iq, ik, g=group: (b, h // g, ik, 0))
 
     out = pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, bq, 1, hdp), lambda b, h, iq, ik: (b, iq, h, 0)),
-            pl.BlockSpec((1, bk, 1, hdp),
-                         lambda b, h, iq, ik, g=group: (b, ik, h // g, 0)),
-            pl.BlockSpec((1, bk, 1, hdp),
-                         lambda b, h, iq, ik, g=group: (b, ik, h // g, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, bq, 1, hdp),
-                               lambda b, h, iq, ik: (b, iq, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Sqp, Hq, hdp), q.dtype),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct((B, Hq, Sqp, hdp), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((bq, 1), jnp.float32),      # running max
             pltpu.VMEM((bq, 1), jnp.float32),      # running denom
@@ -147,4 +147,4 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                                  "arbitrary")),
         interpret=interpret,
     )(q, k, v)
-    return out[:, :Sq, :, :hd]
+    return out[:, :, :Sq, :hd].transpose(0, 2, 1, 3)

@@ -1,8 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
-                           + " --xla_force_host_platform_device_count=512")
-# ^ MUST precede every other import (jax locks device count on first init).
-
 """Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell.
 
 For each cell this script
@@ -19,7 +14,19 @@ For each cell this script
 Usage:
   python -m repro.launch.dryrun --arch granite-3-2b --shape train_4k --mesh pod1
   python -m repro.launch.dryrun --all --out results/dryrun.jsonl
+
+It is a host-only lowering: run as a program, it pins JAX to the CPU and
+forces 512 host devices before JAX is imported, so it never holds a chip.
 """
+
+import os
+
+if __name__ == "__main__":
+    # MUST precede the jax import: jax fixes its platform and host device
+    # count on first use
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
+                               + " --xla_force_host_platform_device_count=512")
 
 import argparse
 import json
@@ -36,33 +43,10 @@ from repro.dist.hints import sharding_rules
 from repro.launch import hlo_analysis
 from repro.launch.mesh import make_production_mesh
 from repro.launch.specs import input_specs
-from repro.train.optimizer import OptConfig, init_opt_state
+from repro.train.optimizer import init_opt_state
 from repro.train.train_step import (make_prefill_step, make_serve_step,
-                                    make_train_step)
-
-
-def opt_config_for(cfg) -> OptConfig:
-    from repro.models import param_count
-    big = param_count(cfg) > 80e9
-    return OptConfig(moment_dtype="bfloat16" if big else "float32")
-
-
-def microbatches_for(cfg, shape) -> tuple[int, object]:
-    """Gradient-accumulation depth per train cell (memory-term control):
-    activations scale with tokens-per-pass. Giant models also accumulate in
-    bf16 (an f32 accumulator alone would be 2.7 TB for deepseek-v3)."""
-    import jax.numpy as jnp
-    from repro.models import param_count
-    n = param_count(cfg)
-    if shape.kind != "train":
-        return 1, None
-    if n > 80e9:
-        return 8, jnp.bfloat16
-    if n > 20e9 or cfg.family == "hybrid":
-        return 8, None
-    if n > 8e9:
-        return 4, None
-    return 2, None
+                                    microbatches_for, opt_config_for,
+                                    sharded_train_step)
 
 
 def run_cell(arch: str, shape_name: str, mesh_kind: str,
@@ -76,43 +60,34 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str,
         mesh = make_production_mesh(multi_pod=(mesh_kind == "pod2"))
         specs = input_specs(cfg, shape)
         with mesh:
+            p_specs = specs["params"]
             if shape.kind == "train":
                 mb, acc_dt = microbatches_for(cfg, shape)
                 rec["microbatches"] = mb
-                step = make_train_step(
-                    cfg, opt_config_for(cfg), microbatches=mb,
-                    accum_dtype=acc_dt,
-                    grad_specs=shd.param_specs(cfg, specs["params"], mesh))
-                p_specs = specs["params"]
-                o_specs = jax.eval_shape(
-                    lambda: init_opt_state(opt_config_for(cfg), p_specs))
-                in_sh = (shd.named(mesh, shd.param_specs(cfg, p_specs, mesh)),
-                         shd.named(mesh, {
-                             "m": shd.param_specs(cfg, p_specs, mesh),
-                             "v": shd.param_specs(cfg, p_specs, mesh),
-                             "step": jax.sharding.PartitionSpec()}),
-                         shd.named(mesh, shd.batch_specs(
-                             cfg, specs["batch"], mesh)))
+                oc = opt_config_for(cfg)
+                jitted, _ = sharded_train_step(cfg, oc, mesh, specs["batch"],
+                                               microbatches=mb,
+                                               accum_dtype=acc_dt)
+                o_specs = jax.eval_shape(lambda: init_opt_state(oc, p_specs))
                 args = (p_specs, o_specs, specs["batch"])
             elif shape.kind == "prefill":
-                step = make_prefill_step(cfg, shape.seq_len)
-                p_specs = specs["params"]
                 in_sh = (shd.named(mesh, shd.param_specs(cfg, p_specs, mesh)),
                          shd.named(mesh, shd.batch_specs(
                              cfg, specs["batch"], mesh)))
+                jitted = jax.jit(make_prefill_step(cfg, shape.seq_len),
+                                 in_shardings=in_sh)
                 args = (p_specs, specs["batch"])
             else:  # decode
-                step = make_serve_step(cfg)
-                p_specs = specs["params"]
                 in_sh = (shd.named(mesh, shd.param_specs(cfg, p_specs, mesh)),
                          shd.named(mesh, shd.decode_state_specs(
                              cfg, specs["state"], mesh)),
                          shd.named(mesh, shd.batch_specs(
                              cfg, {"t": specs["tokens"]}, mesh))["t"])
+                jitted = jax.jit(make_serve_step(cfg), in_shardings=in_sh)
                 args = (p_specs, specs["state"], specs["tokens"])
 
             with sharding_rules(mesh):
-                lowered = jax.jit(step, in_shardings=in_sh).lower(*args)
+                lowered = jitted.lower(*args)
             compiled = lowered.compile()
 
             ca = compiled.cost_analysis() or {}

@@ -3,14 +3,17 @@
 ``make_production_mesh`` is a FUNCTION (not a module constant) so importing
 this module never touches jax device state. The dry-run entrypoint sets
 ``XLA_FLAGS=--xla_force_host_platform_device_count=512`` BEFORE importing jax;
-everything else (tests, benches) sees the real single CPU device and builds
-1×1 meshes via :func:`make_local_mesh`.
+everything else (tests, benches, training) sees the real local devices and
+builds small meshes via :mod:`repro.dist.mesh`. Axes are ``Auto``, as there:
+``Explicit`` axes (``jax.make_mesh``'s default) refuse the model's
+``with_sharding_constraint`` hints.
 """
 
 from __future__ import annotations
 
 import jax
 import numpy as np
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -27,11 +30,5 @@ def make_production_mesh(*, multi_pod: bool = False):
         raise RuntimeError(
             f"mesh {shape} needs {need} devices, found {len(devices)} — run "
             "under launch/dryrun.py (it forces 512 host devices) or on a pod")
-    return jax.make_mesh(shape, axes, devices=devices[:need])
-
-
-def make_local_mesh(data: int = 1, model: int = 1):
-    """Tiny mesh over the real local devices (CPU tests / examples)."""
-    n = data * model
-    return jax.make_mesh((data, model), ("data", "model"),
-                         devices=jax.devices()[:n])
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices[:need])

@@ -1,7 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
-                           + " --xla_force_host_platform_device_count=512")
-
 """Per-cell dry-run profiler — the §Perf loop's microscope.
 
 Compiles ONE (arch × shape × mesh) cell exactly as launch/dryrun.py does and
@@ -11,9 +7,13 @@ change moved.
 
   python -m repro.launch.profile_cell --arch arctic-480b --shape train_4k \
       --mesh pod1 [--save results/cell.hlo]
+
+Like the dry-run it is host-only: ``main`` pins JAX to the CPU and forces
+512 host devices before anything imports JAX.
 """
 
 import argparse
+import os
 
 from repro.launch import hlo_analysis as H
 
@@ -25,50 +25,43 @@ def profile(arch: str, shape_name: str, mesh_kind: str,
     from repro.configs import SHAPES, get_config
     from repro.dist import sharding as shd
     from repro.dist.hints import sharding_rules
-    from repro.launch.dryrun import microbatches_for, opt_config_for
     from repro.launch.mesh import make_production_mesh
     from repro.launch.specs import input_specs
     from repro.train.optimizer import init_opt_state
     from repro.train.train_step import (make_prefill_step, make_serve_step,
-                                        make_train_step)
+                                        microbatches_for, opt_config_for,
+                                        sharded_train_step)
 
     cfg = get_config(arch)
     shape = SHAPES[shape_name]
     mesh = make_production_mesh(multi_pod=(mesh_kind == "pod2"))
     specs = input_specs(cfg, shape)
     with mesh:
+        p = specs["params"]
         if shape.kind == "train":
             mb, acc = microbatches_for(cfg, shape)
-            step = make_train_step(
-                cfg, opt_config_for(cfg), microbatches=mb, accum_dtype=acc,
-                grad_specs=shd.param_specs(cfg, specs["params"], mesh))
-            p = specs["params"]
-            o = jax.eval_shape(lambda: init_opt_state(opt_config_for(cfg), p))
-            in_sh = (shd.named(mesh, shd.param_specs(cfg, p, mesh)),
-                     shd.named(mesh, {"m": shd.param_specs(cfg, p, mesh),
-                                      "v": shd.param_specs(cfg, p, mesh),
-                                      "step": jax.sharding.PartitionSpec()}),
-                     shd.named(mesh, shd.batch_specs(cfg, specs["batch"],
-                                                     mesh)))
+            oc = opt_config_for(cfg)
+            jitted, _ = sharded_train_step(cfg, oc, mesh, specs["batch"],
+                                           microbatches=mb, accum_dtype=acc)
+            o = jax.eval_shape(lambda: init_opt_state(oc, p))
             args = (p, o, specs["batch"])
         elif shape.kind == "prefill":
-            step = make_prefill_step(cfg, shape.seq_len)
-            p = specs["params"]
             in_sh = (shd.named(mesh, shd.param_specs(cfg, p, mesh)),
                      shd.named(mesh, shd.batch_specs(cfg, specs["batch"],
                                                      mesh)))
+            jitted = jax.jit(make_prefill_step(cfg, shape.seq_len),
+                             in_shardings=in_sh)
             args = (p, specs["batch"])
         else:
-            step = make_serve_step(cfg)
-            p = specs["params"]
             in_sh = (shd.named(mesh, shd.param_specs(cfg, p, mesh)),
                      shd.named(mesh, shd.decode_state_specs(
                          cfg, specs["state"], mesh)),
                      shd.named(mesh, shd.batch_specs(
                          cfg, {"t": specs["tokens"]}, mesh))["t"])
+            jitted = jax.jit(make_serve_step(cfg), in_shardings=in_sh)
             args = (p, specs["state"], specs["tokens"])
         with sharding_rules(mesh, seq_parallel=seq_parallel):
-            compiled = jax.jit(step, in_shardings=in_sh).lower(*args).compile()
+            compiled = jitted.lower(*args).compile()
     txt = compiled.as_text()
     if save:
         with open(save, "w") as f:
@@ -101,6 +94,9 @@ def profile(arch: str, shape_name: str, mesh_kind: str,
 
 
 def main() -> None:
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
+                               + " --xla_force_host_platform_device_count=512")
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--shape", required=True)

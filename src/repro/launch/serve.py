@@ -16,6 +16,7 @@ import numpy as np
 
 from repro.configs import ARCH_NAMES, get_smoke
 from repro.core.locstore import LocStore
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import init_params
 from repro.serve.engine import Router, ServingEngine
 
@@ -31,6 +32,7 @@ def main() -> None:
     ap.add_argument("--max-batch", type=int, default=4)
     args = ap.parse_args()
 
+    use_compile_cache()
     cfg = get_smoke(args.arch)
     params = init_params(cfg, jax.random.PRNGKey(0))
     store = LocStore(args.engines)

@@ -7,6 +7,7 @@ Public surface (all pure functions of a frozen :class:`ModelConfig`):
   prefill(cfg, params, batch, max_seq)  -> (last_logits, decode_state)
   init_decode_state(cfg, batch, max_seq)-> decode_state        [for dry-run]
   decode_step(cfg, params, state, tok)  -> (logits, decode_state)
+  forward_logits(cfg, params, tokens)   -> (B,S,V) logits, no cache [dense]
   param_count(cfg) / active_param_count(cfg)
 
 Batch convention: ``{"tokens": (B,S) i32, "labels": (B,S) i32}`` plus
@@ -1222,6 +1223,17 @@ def init_decode_state(cfg: ModelConfig, batch: int, max_seq: int) -> Pytree:
 def decode_step(cfg: ModelConfig, params: Pytree, state: Pytree,
                 tokens: jax.Array):
     return _FAMILY[cfg.family][4](cfg, params, state, tokens)
+
+
+def forward_logits(cfg: ModelConfig, params: Pytree,
+                   tokens: jax.Array) -> jax.Array:
+    """Logits at every position from one cache-free forward pass — the
+    reference that cached decode is checked against. Dense-attention
+    families only (``dense``, ``localglobal``)."""
+    if cfg.family not in ("dense", "localglobal"):
+        raise NotImplementedError(
+            f"forward_logits: no cache-free forward for {cfg.family!r}")
+    return _head(cfg, params, _dense_hidden(cfg, params, tokens))
 
 
 # ------------------------------------------------------------------- counts

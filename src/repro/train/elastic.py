@@ -19,13 +19,13 @@ from __future__ import annotations
 from typing import Any
 
 import jax
-from jax.sharding import Mesh, NamedSharding
+from jax.sharding import Mesh
 
 from repro.configs.base import ModelConfig
-from repro.dist import sharding as shd
 from repro.models import model as M
 from repro.train import checkpoint as ckpt
 from repro.train.optimizer import OptConfig, init_opt_state
+from repro.train.train_step import state_shardings
 
 Pytree = Any
 
@@ -36,18 +36,14 @@ def shard_targets(cfg: ModelConfig, opt_cfg: OptConfig, mesh: Mesh
     p_shapes = jax.eval_shape(
         lambda: M.init_params(cfg, jax.random.PRNGKey(0)))
     o_shapes = jax.eval_shape(lambda: init_opt_state(opt_cfg, p_shapes))
-    p_spec = shd.param_specs(cfg, p_shapes, mesh)
-    o_spec = {"m": p_spec, "v": p_spec,
-              "step": jax.sharding.PartitionSpec()}
+    p_sh, o_sh = state_shardings(cfg, mesh)
 
-    def attach(shapes, specs):
+    def attach(shapes, shardings):
         return jax.tree.map(
-            lambda s, sp: jax.ShapeDtypeStruct(
-                s.shape, s.dtype, sharding=NamedSharding(mesh, sp)),
-            shapes, specs,
-            is_leaf=lambda x: isinstance(x, jax.ShapeDtypeStruct))
+            lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+            shapes, shardings)
 
-    return {"p": attach(p_shapes, p_spec), "o": attach(o_shapes, o_spec)}
+    return {"p": attach(p_shapes, p_sh), "o": attach(o_shapes, o_sh)}
 
 
 def elastic_restore(cfg: ModelConfig, opt_cfg: OptConfig, ckpt_dir: str,

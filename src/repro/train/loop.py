@@ -8,11 +8,17 @@ Fault-tolerance contract:
     elastic restart) and continues; steps since the last checkpoint re-run;
   * the data pipeline is deterministic-by-step, so restarts replay the exact
     batches (no data loss / duplication beyond the rolled-back steps).
+
+Training always runs on a mesh (one device gives a 1×1 mesh): params and
+moments are created in the rule-derived shardings of
+:mod:`repro.dist.sharding`, each batch shards over the DP axes, and a restart
+restores onto the mesh (:func:`repro.train.elastic.elastic_restore`).
 """
 
 from __future__ import annotations
 
 import dataclasses
+import math
 import time
 from typing import Any, Callable
 
@@ -21,10 +27,13 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.data.pipeline import PrefetchingLoader, SyntheticCorpus
+from repro.dist.hints import sharding_rules
+from repro.dist.mesh import make_host_mesh
 from repro.models import model as M
 from repro.train import checkpoint as ckpt
+from repro.train.elastic import elastic_restore
 from repro.train.optimizer import OptConfig, init_opt_state
-from repro.train.train_step import make_train_step
+from repro.train.train_step import sharded_train_step
 
 Pytree = Any
 
@@ -40,6 +49,8 @@ class TrainConfig:
     log_every: int = 10
     simulate_failure_at: int | None = None
     seed: int = 0
+    microbatches: int = 1
+    accum_dtype: Any = None          # gradient accumulator; None = float32
 
 
 @dataclasses.dataclass
@@ -49,6 +60,24 @@ class TrainResult:
     restarts: int
     wall_seconds: float
     data_waits: int
+
+
+def check_state_fits(shapes: Pytree, shardings: Pytree, mesh) -> None:
+    """Raise MemoryError before any allocation when one device's shards of
+    the training state (params and moments, not yet gradients or
+    activations) exceed that device's memory. Backends that report no
+    ``bytes_limit`` (the CPU) are not checked."""
+    limit = (mesh.devices.flat[0].memory_stats() or {}).get("bytes_limit")
+    if not limit:
+        return
+    per_device = sum(
+        math.prod(sh.shard_shape(s.shape)) * s.dtype.itemsize
+        for s, sh in zip(jax.tree.leaves(shapes), jax.tree.leaves(shardings)))
+    if per_device > limit:
+        raise MemoryError(
+            f"training state needs {per_device / 2**30:.1f} GiB per device on "
+            f"mesh {dict(mesh.shape)}, over the {limit / 2**30:.1f} GiB a "
+            "device holds: use more devices")
 
 
 def extras_fn(cfg: ModelConfig, batch_np: dict, rng: np.random.Generator
@@ -67,16 +96,28 @@ def extras_fn(cfg: ModelConfig, batch_np: dict, rng: np.random.Generator
 
 def train(cfg: ModelConfig, tc: TrainConfig,
           opt_cfg: OptConfig | None = None,
-          on_step: Callable[[int, dict], None] | None = None) -> TrainResult:
+          on_step: Callable[[int, dict], None] | None = None,
+          mesh=None) -> TrainResult:
+    """Train ``cfg`` for ``tc.steps`` steps on ``mesh`` (default: every local
+    device, :func:`repro.dist.mesh.make_host_mesh`)."""
     opt_cfg = opt_cfg or OptConfig(warmup_steps=10, total_steps=tc.steps)
     cfg.validate()
-    rng = np.random.default_rng(tc.seed)
-
-    params = M.init_params(cfg, jax.random.PRNGKey(tc.seed))
-    opt_state = init_opt_state(opt_cfg, params)
-    step_fn = jax.jit(make_train_step(cfg, opt_cfg), donate_argnums=(0, 1))
-
+    mesh = make_host_mesh() if mesh is None else mesh
     corpus = SyntheticCorpus(cfg.vocab, seed=tc.seed)
+    sample = extras_fn(cfg, next(corpus.batches(tc.batch, tc.seq)),
+                       np.random.default_rng(0))
+    step_fn, (p_sh, o_sh, _) = sharded_train_step(
+        cfg, opt_cfg, mesh, sample, microbatches=tc.microbatches,
+        accum_dtype=tc.accum_dtype)
+
+    def fresh_state():
+        params = M.init_params(cfg, jax.random.PRNGKey(tc.seed))
+        return params, init_opt_state(opt_cfg, params)
+
+    check_state_fits(jax.eval_shape(fresh_state), (p_sh, o_sh), mesh)
+    init = jax.jit(fresh_state, out_shardings=(p_sh, o_sh))
+    params, opt_state = init()
+
     checkpointer = (ckpt.AsyncCheckpointer(tc.ckpt_dir)
                     if tc.ckpt_dir else None)
 
@@ -108,18 +149,11 @@ def train(cfg: ModelConfig, tc: TrainConfig,
                 if restore_step is None:
                     # failed before the first checkpoint: cold restart —
                     # deterministic init + data pipeline replay from step 0
-                    params = M.init_params(cfg, jax.random.PRNGKey(tc.seed))
-                    opt_state = init_opt_state(opt_cfg, params)
+                    params, opt_state = init()
                     restore_step = 0
                 else:
-                    tgt_p = jax.eval_shape(
-                        lambda: M.init_params(cfg,
-                                              jax.random.PRNGKey(tc.seed)))
-                    tgt_o = jax.eval_shape(
-                        lambda: init_opt_state(opt_cfg, tgt_p))
-                    state = ckpt.restore(tc.ckpt_dir, restore_step,
-                                         target={"p": tgt_p, "o": tgt_o})
-                    params, opt_state = state["p"], state["o"]
+                    params, opt_state, _ = elastic_restore(
+                        cfg, opt_cfg, tc.ckpt_dir, mesh, restore_step)
                 step = restore_step
                 restarts += 1
                 loader.close()
@@ -127,7 +161,8 @@ def train(cfg: ModelConfig, tc: TrainConfig,
                 continue
 
             batch = next(loader)
-            params, opt_state, metrics = step_fn(params, opt_state, batch)
+            with sharding_rules(mesh):
+                params, opt_state, metrics = step_fn(params, opt_state, batch)
             step += 1
             loss = float(metrics["loss"])
             losses.append(loss)

@@ -1,10 +1,11 @@
 """The jitted train/serve steps, with sharding attached.
 
-``make_train_step(cfg, opt_cfg)`` returns ``step(params, opt_state, batch)``
-suitable for ``jax.jit(..., donate_argnums=(0, 1))`` under a mesh; shardings
-come from :mod:`repro.dist.sharding`. The same function is what the dry-run
-lowers for every (arch × train shape) cell, so there is exactly one train-step
-definition in the framework.
+``make_train_step(cfg, opt_cfg)`` returns ``step(params, opt_state, batch)``;
+:func:`sharded_train_step` jits it onto a mesh with the shardings of
+:mod:`repro.dist.sharding`. The same function is what the dry-run lowers for
+every (arch × train shape) cell, what the training loop runs on a mesh and
+what ``chip_smoke.py --chips 4`` runs on four chips, so there is exactly one
+train-step definition in the framework.
 """
 
 from __future__ import annotations
@@ -12,12 +13,37 @@ from __future__ import annotations
 from typing import Any
 
 import jax
+import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
-from repro.configs.base import ModelConfig
+from repro.configs.base import InputShape, ModelConfig
+from repro.dist import sharding as shd
 from repro.models import model as M
 from repro.train.optimizer import OptConfig, adamw_update
 
 Pytree = Any
+
+
+def opt_config_for(cfg: ModelConfig) -> OptConfig:
+    """Giant models keep their AdamW moments in bf16."""
+    big = M.param_count(cfg) > 80e9
+    return OptConfig(moment_dtype="bfloat16" if big else "float32")
+
+
+def microbatches_for(cfg: ModelConfig, shape: InputShape) -> tuple[int, Any]:
+    """Gradient-accumulation depth per train cell (memory-term control):
+    activations scale with tokens-per-pass. Giant models also accumulate in
+    bf16 (an f32 accumulator alone would be 2.7 TB for deepseek-v3)."""
+    n = M.param_count(cfg)
+    if shape.kind != "train":
+        return 1, None
+    if n > 80e9:
+        return 8, jnp.bfloat16
+    if n > 20e9 or cfg.family == "hybrid":
+        return 8, None
+    if n > 8e9:
+        return 4, None
+    return 2, None
 
 
 def make_train_step(cfg: ModelConfig, opt_cfg: OptConfig, *,
@@ -30,7 +56,7 @@ def make_train_step(cfg: ModelConfig, opt_cfg: OptConfig, *,
     the giant configs pass bf16 (a f32 grad accumulator alone would be 2.7 TB
     for deepseek-v3).
 
-    ``grad_specs`` (a PartitionSpec tree matching params) constrains each
+    ``grad_specs`` (a sharding tree matching params) constrains each
     microbatch's gradients to the accumulator's sharding BEFORE the add —
     without it XLA all-reduces the full gradient then slices (measured 948 GiB
     × L × mb of f32 all-reduce on arctic train_4k); with it the batch-axis
@@ -74,6 +100,35 @@ def make_train_step(cfg: ModelConfig, opt_cfg: OptConfig, *,
         return params, opt_state, metrics
 
     return train_step
+
+
+def state_shardings(cfg: ModelConfig, mesh) -> tuple[Pytree, Pytree]:
+    """NamedShardings on ``mesh`` for the training state (params,
+    opt_state): the moments take their parameter's sharding, the step count
+    is replicated. The train step's shardings and a restore's targets
+    (:func:`repro.train.elastic.shard_targets`) both come from here."""
+    p_shapes = jax.eval_shape(lambda: M.init_params(cfg, jax.random.PRNGKey(0)))
+    p_specs = shd.param_specs(cfg, p_shapes, mesh)
+    return (shd.named(mesh, p_specs),
+            shd.named(mesh, {"m": p_specs, "v": p_specs, "step": P()}))
+
+
+def sharded_train_step(cfg: ModelConfig, opt_cfg: OptConfig, mesh,
+                       batch: Pytree, *, microbatches: int = 1,
+                       accum_dtype=None):
+    """The train step jitted onto ``mesh``: params and moments stay in their
+    rule-derived shardings across steps (donated), the batch (arrays or
+    ShapeDtypeStructs) shards over the DP axes. Returns
+    ``(jitted_step, (param_sh, opt_sh, batch_sh))``. Trace (call or
+    ``.lower``) it inside ``dist.hints.sharding_rules(mesh)`` so the model's
+    activation hints bind to the mesh."""
+    p_sh, o_sh = state_shardings(cfg, mesh)
+    b_sh = shd.named(mesh, shd.batch_specs(cfg, batch, mesh))
+    step = make_train_step(cfg, opt_cfg, microbatches=microbatches,
+                           accum_dtype=accum_dtype, grad_specs=p_sh)
+    jitted = jax.jit(step, in_shardings=(p_sh, o_sh, b_sh),
+                     out_shardings=(p_sh, o_sh, None), donate_argnums=(0, 1))
+    return jitted, (p_sh, o_sh, b_sh)
 
 
 def make_serve_step(cfg: ModelConfig):

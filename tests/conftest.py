@@ -9,5 +9,3 @@ import jax  # noqa: E402
 
 jax.config.update("jax_enable_x64", False)
 
-import repro  # noqa: E402,F401 — installs the jax API compat shims
-

@@ -26,7 +26,7 @@ _MESH = Mesh(np.array(jax.devices()[:1]), ("d",))
 _PSUM = jax.jit(jax.shard_map(
     lambda a, e: compressed_psum(a, "d", e),
     mesh=_MESH, in_specs=jax.sharding.PartitionSpec(),
-    out_specs=jax.sharding.PartitionSpec()))
+    out_specs=jax.sharding.PartitionSpec(), check_vma=False))
 
 
 def _psum_1dev(x, err):
@@ -64,7 +64,7 @@ def test_wrap_grads_pytree():
         return wrap_grads(g, "d", None)
 
     sm = jax.shard_map(f, mesh=mesh, in_specs=jax.sharding.PartitionSpec(),
-                       out_specs=jax.sharding.PartitionSpec())
+                       out_specs=jax.sharding.PartitionSpec(), check_vma=False)
     out, err = sm(grads)
     np.testing.assert_allclose(np.asarray(out["a"]), 1.0, rtol=1e-2)
     np.testing.assert_allclose(np.asarray(out["b"]["c"]), -2.0, rtol=1e-2)

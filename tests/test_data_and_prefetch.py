@@ -3,6 +3,7 @@
 import time
 
 import numpy as np
+import pytest
 
 from repro.configs import get_smoke
 from repro.core.locstore import LocStore, SimObject
@@ -80,6 +81,30 @@ class TestPrefetchEngine:
         store = LocStore(2)
         store.put("d", SimObject(1), loc=0)
         assert PrefetchEngine(store).wait("d", 1) is False
+
+    def test_hbm_stage_copies_to_device(self):
+        import jax
+        store = LocStore(2)
+        store.put("d", np.arange(8, dtype=np.float32), loc=0)
+        dev = jax.devices()[0]
+        eng = PrefetchEngine(store, device_of=lambda node: dev)
+        eng.submit("d", 1, tier="hbm")
+        eng.drain()
+        copy = eng.device_copy("d", 1)
+        assert isinstance(copy, jax.Array) and copy.devices() == {dev}
+        assert eng.report()["device_puts"] == 1
+
+    def test_failed_device_put_raises(self):
+        """A device copy that fails must surface, not pass as a host replica."""
+        store = LocStore(2)
+        store.put("d", np.arange(8, dtype=np.float32), loc=0)
+        eng = PrefetchEngine(store, device_of=lambda node: "no-such-device")
+        eng.submit("d", 1, tier="hbm")
+        with pytest.raises(ValueError, match="device_put"):
+            eng.drain()
+        assert eng.device_copy("d", 1) is None
+        assert eng.report()["device_puts"] == 0
+        assert not store.stat("d").resident_on(1)
 
 
 def test_epoch_workflow_schedules_with_locality():

@@ -8,7 +8,7 @@ from jax.sharding import AbstractMesh
 from repro.dist import sharding as shd
 from repro.dist.compression import quantize_int8
 from repro.dist.hints import get_rules, hint, sharding_rules
-from repro.launch.mesh import make_local_mesh
+from repro.dist.mesh import make_local_mesh
 
 
 def mesh1():

@@ -9,7 +9,7 @@ import pytest
 
 from repro.configs import get_smoke
 from repro.dist.hints import hint, sharding_rules, tp_divides
-from repro.launch.mesh import make_local_mesh
+from repro.dist.mesh import make_local_mesh
 from repro.models.moe import _moe_ffn_global, init_moe, moe_ffn
 
 
@@ -17,6 +17,14 @@ def test_hint_noop_without_rules():
     x = jnp.ones((4, 8))
     y = hint(x, "dp", "tp")
     assert y is x                      # identity, not even a constraint
+
+
+def test_make_local_mesh_axes_are_auto():
+    """``with_sharding_constraint`` (every ``hint``) refuses Explicit axes,
+    which ``jax.make_mesh`` now builds by default."""
+    mesh = make_local_mesh(1, 1)
+    assert mesh.axis_names == ("data", "model")
+    assert mesh.axis_types == (jax.sharding.AxisType.Auto,) * 2
 
 
 def test_hint_applies_under_rules():

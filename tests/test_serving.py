@@ -13,7 +13,7 @@ from repro.configs import get_smoke
 from repro.core.locstore import (LocStore, StorageHierarchy, TierSpec,
                                  tiered_hierarchy)
 from repro.core.prefetch import PrefetchEngine
-from repro.models import decode_step, init_params, prefill
+from repro.models import decode_step, forward_logits, init_params, prefill
 from repro.serve.engine import (Router, ServingEngine, _cache_name,
                                 _read_slot, _write_slot)
 
@@ -48,6 +48,26 @@ def test_batched_sessions_isolated(setup):
         eng.step()
     a_batched = eng.sessions[sa].tokens[:5]
     assert a_batched == a_solo[:5]
+
+
+def test_cached_decode_matches_cache_free_forward(setup):
+    """The serving reference (chip_smoke.py runs it at full width): logits
+    decode produced through the KV cache equal those of one cache-free
+    forward pass over prompt + generated tokens."""
+    cfg, params = setup
+    prompt = [3, 1, 4, 1, 5, 9, 2, 6]
+    batch = {"tokens": jnp.asarray([prompt], jnp.int32)}
+    batch["labels"] = batch["tokens"]
+    logits, state = prefill(cfg, params, batch, 32)
+    tokens, rows = [int(jnp.argmax(logits[0, -1]))], []
+    for _ in range(6):
+        logits, state = decode_step(cfg, params, state,
+                                    jnp.asarray([[tokens[-1]]], jnp.int32))
+        rows.append(np.asarray(logits[0, -1]))
+        tokens.append(int(jnp.argmax(logits[0, -1])))
+    seq = jnp.asarray([prompt + tokens[:-1]], jnp.int32)
+    want = np.asarray(forward_logits(cfg, params, seq)[0, len(prompt):])
+    np.testing.assert_allclose(np.stack(rows), want, rtol=1e-4, atol=1e-4)
 
 
 def test_write_slot_roundtrip(setup):

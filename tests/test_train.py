@@ -1,20 +1,29 @@
 """Training loop: convergence, checkpoint/restart determinism, elasticity,
 optimizer behaviour."""
 
+import dataclasses
+import itertools
 import os
 import tempfile
+import types
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import NamedSharding
+from jax.sharding import PartitionSpec as P
 
 from repro.configs import get_smoke
+from repro.data.pipeline import SyntheticCorpus
+from repro.dist.mesh import make_local_mesh
+from repro.models import model as M
 from repro.train import checkpoint as ckpt
 from repro.train.elastic import elastic_restore
-from repro.train.loop import TrainConfig, train
+from repro.train.loop import TrainConfig, check_state_fits, train
 from repro.train.optimizer import (OptConfig, adamw_update, global_norm,
                                    init_opt_state, schedule)
+from repro.train.train_step import make_train_step
 
 
 def test_loss_decreases():
@@ -77,12 +86,12 @@ def test_elastic_restore_new_mesh():
     mesh with rule-derived shardings (full reshard path)."""
     cfg = get_smoke("granite-3-2b")
     oc = OptConfig()
-    from repro.models import model as M
     params = M.init_params(cfg, jax.random.PRNGKey(0))
     opt = init_opt_state(oc, params)
     with tempfile.TemporaryDirectory() as d:
         ckpt.save({"p": params, "o": opt}, d, 11)
         mesh = jax.make_mesh((1, 1), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2,
                              devices=jax.devices()[:1])
         p2, o2, step = elastic_restore(cfg, oc, d, mesh)
     assert step == 11
@@ -126,6 +135,48 @@ class TestOptimizer:
     def test_global_norm(self):
         t = {"a": jnp.ones((3,)), "b": jnp.ones((4,))}
         assert float(global_norm(t)) == pytest.approx(np.sqrt(7.0))
+
+
+def test_mesh_train_matches_unsharded_across_restart():
+    """``train`` (sharded step, sharded init, elastic restore onto the host
+    mesh) reproduces a plain jitted step over the same batches, through a
+    failure and a checkpoint restore."""
+    cfg = dataclasses.replace(get_smoke("granite-3-2b"), dtype="float32")
+    oc = OptConfig(warmup_steps=10, total_steps=8)
+    step = jax.jit(make_train_step(cfg, oc, microbatches=2))
+    params = M.init_params(cfg, jax.random.PRNGKey(0))
+    opt = init_opt_state(oc, params)
+    want = []
+    for b in itertools.islice(
+            SyntheticCorpus(cfg.vocab, seed=0).batches(2, 32), 8):
+        params, opt, m = step(params, opt, b)
+        want.append(float(m["loss"]))
+    with tempfile.TemporaryDirectory() as d:
+        r = train(cfg, TrainConfig(steps=8, batch=2, seq=32, ckpt_every=4,
+                                   ckpt_dir=d, simulate_failure_at=6,
+                                   microbatches=2), oc)
+    assert r.restarts == 1 and r.steps_done == 8
+    # steps 1-6, then steps 5-8 again from the step-4 checkpoint
+    np.testing.assert_allclose(r.losses, want[:6] + want[4:], rtol=1e-5)
+
+
+@pytest.mark.parametrize("rows,fits", [(8, True), (16, False)])
+def test_check_state_fits_against_device_limit(rows, fits):
+    """The state's per-device shard bytes are held against the device's
+    ``bytes_limit`` before anything is allocated."""
+    class Dev:
+        def memory_stats(self):
+            return {"bytes_limit": 1000}
+
+    mesh = make_local_mesh(1, 1)
+    shapes = {"w": jax.ShapeDtypeStruct((rows, 16), jnp.float32)}
+    shardings = {"w": NamedSharding(mesh, P())}
+    stub = types.SimpleNamespace(devices=np.array([Dev()]), shape=mesh.shape)
+    if fits:
+        check_state_fits(shapes, shardings, stub)
+    else:
+        with pytest.raises(MemoryError, match="per device"):
+            check_state_fits(shapes, shardings, stub)
 
 
 def test_failure_before_first_checkpoint_cold_restarts():
