@@ -26,6 +26,7 @@ import time
 from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Callable, Mapping, Sequence
 
+from repro import obs
 from repro.core.dag import TaskGraph
 from repro.core.locstore import LocStore, Placement, StorageHierarchy
 from repro.core.prefetch import PrefetchEngine
@@ -156,6 +157,7 @@ class WorkflowExecutor:
         self.store.drain_writebacks()
 
     # ------------------------------------------------------------------ run
+    @obs.traced("executor.run")
     def run(self) -> ExecResult:
         wf = self.wf
         g: TaskGraph = wf.graph
@@ -174,38 +176,41 @@ class WorkflowExecutor:
         errors: list[BaseException] = []
 
         def body(a: Assignment) -> None:
+            with obs.span("task", a.tid):
+                run_task(a)
+
+        def run_task(a: Assignment) -> None:
             nonlocal done_total
             tid = a.tid
             t_assign = time.perf_counter()
             inputs: dict[str, Any] = {}
-            for name in g.tasks[tid].inputs:
-                # prefer a device/prefetched replica; else normal located get
-                self.prefetch.wait(name, a.node, timeout=None) if \
-                    (name, a.node) in self.prefetch._inflight else None
-                dev = self.prefetch.device_copy(name, a.node)
-                if dev is not None:
-                    inputs[name] = dev
-                    self.store.get(name, at=a.node)  # accounting: local hit
-                else:
-                    inputs[name], _ = self.store.get(name, at=a.node)
+            with obs.span("task.stage_in"):
+                for name in g.tasks[tid].inputs:
+                    # prefer a device/prefetched replica; else a located get
+                    if (name, a.node) in self.prefetch._inflight:
+                        self.prefetch.wait(name, a.node, timeout=None)
+                    dev = self.prefetch.device_copy(name, a.node)
+                    if dev is not None:
+                        inputs[name] = dev
+                        self.store.get(name, at=a.node)  # accounting: hit
+                    else:
+                        inputs[name], _ = self.store.get(name, at=a.node)
             t_start = time.perf_counter()
+            body_failed = False
             try:
-                fn = g.tasks[tid].fn
-                out = fn(**inputs) if fn is not None else {}
-                for oname in g.tasks[tid].outputs:
-                    val = out.get(oname) if isinstance(out, Mapping) else None
-                    pin = g.data[oname].pinned_loc
-                    self.store.put(oname, val,
-                                   loc=pin if pin is not None else a.node,
-                                   xattr={"producer": tid})
-                if self.store.durability == "fsync_on_barrier":
-                    # task finish is the executor's sync point: everything
-                    # still dirty (this task's outputs included) becomes
-                    # durable before successors are released
-                    self.store.barrier()
+                with obs.span("task.body"):
+                    fn = g.tasks[tid].fn
+                    out = fn(**inputs) if fn is not None else {}
             except BaseException as e:  # noqa: BLE001 - propagated below
                 errors.append(e)
-            self.prefetch.release(tid)
+                body_failed = True
+            with obs.span("task.put"):
+                try:
+                    if not body_failed:
+                        self._put_outputs(g, tid, a.node, out)
+                except BaseException as e:  # noqa: BLE001 - propagated below
+                    errors.append(e)
+                self.prefetch.release(tid)
             t_end = time.perf_counter()
             with self._cv:
                 self._io_wait += t_start - t_assign
@@ -225,40 +230,34 @@ class WorkflowExecutor:
         with self._cv:
             while done_total < len(g.tasks) and not errors:
                 if ready and self._free:
-                    assignments = self.sched.select(sorted(ready), self.cluster)
-                    for a in assignments:
-                        ready.discard(a.tid)
-                        state[a.tid] = "running"
-                        self._running_at[a.tid] = a.node
-                        self._free.discard(a.node)
-                        pool.submit(body, a)
-                    if isinstance(self.sched, ProactiveScheduler):
-                        cands = [tid for tid, st in state.items()
-                                 if st == "pending" and any(
-                                     self.store.exists(n)
-                                     for n in g.tasks[tid].inputs)]
-                        for req in self.sched.preplace(cands, self.cluster,
-                                                       dict(self._running_at)):
-                            # pinned do-not-evict until for_task finishes, so
-                            # capacity pressure cannot undo the prefetch
-                            self.prefetch.submit(req.data_name, req.dst,
-                                                 tier=req.tier,
-                                                 pin_for=req.for_task)
+                    with obs.span("executor.dispatch"):
+                        assignments = self.sched.select(sorted(ready),
+                                                        self.cluster)
+                        for a in assignments:
+                            ready.discard(a.tid)
+                            state[a.tid] = "running"
+                            self._running_at[a.tid] = a.node
+                            self._free.discard(a.node)
+                            pool.submit(body, a)
+                        if isinstance(self.sched, ProactiveScheduler):
+                            self._preplace(state)
                     if assignments:
                         continue
-                self._cv.wait(timeout=0.5)
-        pool.shutdown(wait=True)
-        self.prefetch.drain()
-        self._wb_stop.set()
-        wb_thread.join(timeout=5.0)
-        if errors:
-            raise errors[0]
-        wall = time.perf_counter() - t0
-        rep = self.store.movement_report()
-        sink_outputs = {}
-        for tid in g.sinks():
-            for oname in g.tasks[tid].outputs:
-                sink_outputs[oname], _ = self.store.get(oname)
+                with obs.span("executor.wait"):
+                    self._cv.wait(timeout=0.5)
+        with obs.span("executor.drain"):
+            pool.shutdown(wait=True)
+            self.prefetch.drain()
+            self._wb_stop.set()
+            wb_thread.join(timeout=5.0)
+            if errors:
+                raise errors[0]
+            wall = time.perf_counter() - t0
+            rep = self.store.movement_report()
+            sink_outputs = {}
+            for tid in g.sinks():
+                for oname in g.tasks[tid].outputs:
+                    sink_outputs[oname], _ = self.store.get(oname)
         return ExecResult(
             wall_seconds=wall,
             io_wait_total=self._io_wait,
@@ -276,3 +275,33 @@ class WorkflowExecutor:
             clean_drops=int(rep["clean_drops"]),
             coord_drops=int(rep["coord_drops"]),
         )
+
+    def _preplace(self, state: dict[str, str]) -> None:
+        """Let the proactive scheduler pre-place the inputs of pending tasks
+        whose inputs partly exist, and start those prefetches. Caller holds
+        ``_cv``."""
+        g = self.wf.graph
+        cands = [tid for tid, st in state.items()
+                 if st == "pending" and any(
+                     self.store.exists(n) for n in g.tasks[tid].inputs)]
+        for req in self.sched.preplace(cands, self.cluster,
+                                       dict(self._running_at)):
+            # pinned do-not-evict until for_task finishes, so capacity
+            # pressure cannot undo the prefetch
+            self.prefetch.submit(req.data_name, req.dst, tier=req.tier,
+                                 pin_for=req.for_task)
+
+    def _put_outputs(self, g: TaskGraph, tid: str, node: int,
+                     out: Any) -> None:
+        """Put a task's outputs at the node that produced them (or at their
+        pinned location)."""
+        for oname in g.tasks[tid].outputs:
+            val = out.get(oname) if isinstance(out, Mapping) else None
+            pin = g.data[oname].pinned_loc
+            self.store.put(oname, val, loc=pin if pin is not None else node,
+                           xattr={"producer": tid})
+        if self.store.durability == "fsync_on_barrier":
+            # task finish is the executor's sync point: everything still
+            # dirty (this task's outputs included) becomes durable before
+            # successors are released
+            self.store.barrier()

@@ -110,6 +110,8 @@ import threading
 import time
 from typing import Any, Iterable, Mapping, Sequence
 
+from repro import obs
+
 __all__ = ["Placement", "SimObject", "Transfer", "TierHop", "TierSpec",
            "StorageHierarchy", "FLAT_HIERARCHY", "tiered_hierarchy",
            "LocationService", "LocStore", "REMOTE_TIER",
@@ -1323,6 +1325,7 @@ class LocStore:
             xattr=prev.xattr if prev is not None else {}))
 
     # ------------------------------------------------------------------ api
+    @obs.traced("store.put")
     def put(self, name: str, value: Any, *, loc: Any | None = None,
             tier: str | None = None,
             xattr: Mapping[str, Any] | None = None,
@@ -1421,6 +1424,7 @@ class LocStore:
             return p.tier
         return p.xattr[key]
 
+    @obs.traced("store.get")
     def get(self, name: str, *, at: int | None = None) -> tuple[Any, Transfer | None]:
         """Read an object from node ``at``; returns (value, movement record).
 
@@ -1500,6 +1504,7 @@ class LocStore:
             self.transfers.append(t)
         return value, t
 
+    @obs.traced("store.promote")
     def promote(self, name: str, node: int, tier: str | None = None) -> Placement:
         """Explicitly move a replica already resident on ``node`` to ``tier``
         (default: top) — the storage half of a device-targeted prefetch. Use
@@ -1518,6 +1523,7 @@ class LocStore:
             self._sync_placement(name)
         return self.stat(name)
 
+    @obs.traced("store.migrate")
     def migrate(self, name: str, loc: Any) -> Transfer:
         """Re-pin an object (the runtime->FS feedback channel).
 
@@ -1566,6 +1572,7 @@ class LocStore:
                 self.transfers.append(tr)      # the copy the re-pin implies
         return tr
 
+    @obs.traced("store.replicate")
     def replicate(self, name: str, extra_nodes: Iterable[int],
                   tier: str | None = None) -> Placement:
         """Add replicas (used by the prefetch engine: the original stays).

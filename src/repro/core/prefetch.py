@@ -27,6 +27,7 @@ import threading
 from concurrent.futures import Future, ThreadPoolExecutor
 from typing import Any, Callable
 
+from repro import obs
 from repro.core.locstore import LocStore
 
 __all__ = ["PrefetchEngine"]
@@ -141,6 +142,7 @@ class PrefetchEngine:
             self.store.unpin(name, dst)
         return len(pinned)
 
+    @obs.traced("prefetch.stage")
     def _stage(self, name: str, dst: int, tier: str) -> Any:
         value, tr = self.store.get(name)  # metadata read, no accounting
         mode_of = getattr(self.store, "write_mode", None)
@@ -172,7 +174,11 @@ class PrefetchEngine:
             fut = self._inflight.get(key)
         if fut is None:
             return False
-        fut.result(timeout=timeout)
+        if fut.done():
+            fut.result()
+        else:
+            with obs.span("prefetch.wait", name):   # the consumer came early
+                fut.result(timeout=timeout)
         return True
 
     def device_copy(self, name: str, dst: int) -> Any | None:

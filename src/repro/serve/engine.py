@@ -53,6 +53,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.configs.base import ModelConfig
 from repro.core.config import ServingConfig
 from repro.core.locstore import DropReport, JoinReport, LocStore, Placement
@@ -116,8 +117,17 @@ class JaxComputeBackend:
         cfg.validate()
         self.cfg = cfg
         self.max_seq = max_seq
-        self._decode = jax.jit(lambda p, s, t: M.decode_step(cfg, p, s, t))
-        self._prefill1 = jax.jit(lambda p, b: M.prefill(cfg, p, b, max_seq))
+
+        # named, so that profiles and compile counts read jit(prefill) and
+        # jit(decode_step)
+        def prefill(p, b):
+            return M.prefill(cfg, p, b, max_seq)
+
+        def decode_step(p, s, t):
+            return M.decode_step(cfg, p, s, t)
+
+        self._decode = jax.jit(decode_step)
+        self._prefill1 = jax.jit(prefill)
         self._template: Pytree | None = None
 
     def init_state(self, batch: int) -> Pytree:
@@ -161,7 +171,9 @@ class JaxComputeBackend:
                tokens: np.ndarray) -> tuple[np.ndarray, Pytree]:
         """One pooled decode step; returns (argmax token per slot, state)."""
         logits, state = self._decode(params, state, jnp.asarray(tokens))
-        return np.asarray(jnp.argmax(logits[:, -1], axis=-1)), state
+        arg = jnp.argmax(logits[:, -1], axis=-1)
+        with obs.span("engine.step.sync"):
+            return np.asarray(arg), state
 
     def write_slot(self, pooled: Pytree, single: Pytree, slot: int) -> Pytree:
         return _write_slot(pooled, single, slot)
@@ -288,7 +300,6 @@ class ServingEngine:
         self.prefills = 0
         self.parks = 0
         self.resumes = 0
-        self.rehydrates = 0
         self.prefill_seconds: float | None = None   # EMA of measured prefills
         self._clock = 0
         self._slot_nbytes: float | None = None
@@ -357,25 +368,31 @@ class ServingEngine:
             raise RuntimeError("engine full")
         slot = self._free_slots.pop()
         sid = next(ServingEngine._SID)
-        first, fresh, dt = self.backend.prefill(self.params, prompt, extras)
-        # measured prefill cost — the router prices migrations with this
-        self.prefill_seconds = (dt if self.prefill_seconds is None
-                                else 0.5 * self.prefill_seconds + 0.5 * dt)
-        self.prefills += 1
-        # copy the single-session state into this slot of the pooled state
-        self.state = self.backend.write_slot(self.state, fresh, slot)
-        sess = Session(sid=sid, slot=slot, prompt_len=len(prompt),
-                       tokens=[first])
-        self.sessions[sid] = sess
-        self._slotted[sid] = sess
-        self._touch(sess)
-        if self.store is not None:
-            # live session: a correctly-SIZED placeholder pinned in the top
-            # tier — eviction and tier_report() must account the real bytes
-            self.store.put(_cache_name(sid),
-                           KVSlice(None, self.slot_bytes()), loc=self.node,
-                           xattr=self._cache_xattr(sid))
-        self._sanitize_check()
+        with obs.span("engine.submit", sid):
+            with obs.span("engine.prefill"):
+                first, fresh, dt = self.backend.prefill(self.params, prompt,
+                                                        extras)
+            # measured prefill cost — the router prices migrations with this
+            self.prefill_seconds = (dt if self.prefill_seconds is None
+                                    else 0.5 * self.prefill_seconds + 0.5 * dt)
+            self.prefills += 1
+            # copy the single-session state into this slot of the pooled state
+            with obs.span("engine.write_slot") as sp:
+                self.state = sp.ready(self.backend.write_slot(self.state,
+                                                              fresh, slot))
+            sess = Session(sid=sid, slot=slot, prompt_len=len(prompt),
+                           tokens=[first])
+            self.sessions[sid] = sess
+            self._slotted[sid] = sess
+            self._touch(sess)
+            if self.store is not None:
+                # live session: a correctly-SIZED placeholder pinned in the
+                # top tier — eviction and tier_report() must account the
+                # real bytes
+                self.store.put(_cache_name(sid),
+                               KVSlice(None, self.slot_bytes()), loc=self.node,
+                               xattr=self._cache_xattr(sid))
+            self._sanitize_check()
         return sid
 
     # ------------------------------------------------------ park / resume
@@ -391,16 +408,18 @@ class ServingEngine:
             raise RuntimeError(f"session {sid} already finished")
         if s.slot is None:
             return                                   # already parked
-        state = self.backend.read_slot(self.state, self._slot_template(),
-                                       s.slot)
-        self.store.put(_cache_name(sid), KVSlice(state, self.slot_bytes()),
-                       loc=self.node, tier=self.idle_tier,
-                       xattr=self._cache_xattr(sid))
-        self._free_slots.append(s.slot)
-        s.slot = None
-        self._slotted.pop(sid, None)
-        self.parks += 1
-        self._sanitize_check()
+        with obs.span("engine.park", sid):
+            with obs.span("engine.read_slot") as sp:
+                state = sp.ready(self.backend.read_slot(
+                    self.state, self._slot_template(), s.slot))
+            self.store.put(_cache_name(sid), KVSlice(state, self.slot_bytes()),
+                           loc=self.node, tier=self.idle_tier,
+                           xattr=self._cache_xattr(sid))
+            self._free_slots.append(s.slot)
+            s.slot = None
+            self._slotted.pop(sid, None)
+            self.parks += 1
+            self._sanitize_check()
 
     def park_lru(self) -> int | None:
         """Park the least-recently-active slotted session (to make room).
@@ -465,21 +484,23 @@ class ServingEngine:
             return False
         if not self._free_slots:
             raise RuntimeError("engine full")
-        value, _ = self.store.get(_cache_name(sid), at=self.node)
-        if not isinstance(value, KVSlice) or value.state is None:
-            raise RuntimeError(f"session {sid} has no parked KV state")
-        slot = self._free_slots.pop()
-        self.state = self.backend.write_slot(self.state, value.state, slot)
-        s.slot = slot
-        self._slotted[sid] = s
-        self._touch(s)
-        self.resumes += 1
-        self.rehydrates += 1
-        # live again: swap the stored slice back to a sized placeholder in
-        # the top tier (the authoritative KV is in the engine slot now)
-        self.store.put(_cache_name(sid), KVSlice(None, self.slot_bytes()),
-                       loc=self.node, xattr=self._cache_xattr(sid))
-        self._sanitize_check()
+        with obs.span("engine.resume", sid):
+            value, _ = self.store.get(_cache_name(sid), at=self.node)
+            if not isinstance(value, KVSlice) or value.state is None:
+                raise RuntimeError(f"session {sid} has no parked KV state")
+            slot = self._free_slots.pop()
+            with obs.span("engine.write_slot") as sp:
+                self.state = sp.ready(self.backend.write_slot(
+                    self.state, value.state, slot))
+            s.slot = slot
+            self._slotted[sid] = s
+            self._touch(s)
+            self.resumes += 1
+            # live again: swap the stored slice back to a sized placeholder
+            # in the top tier (the authoritative KV is in the engine slot now)
+            self.store.put(_cache_name(sid), KVSlice(None, self.slot_bytes()),
+                           loc=self.node, xattr=self._cache_xattr(sid))
+            self._sanitize_check()
         return True
 
     # ---------------------------------------------------------------- decode
@@ -488,21 +509,23 @@ class ServingEngine:
         live = [s for s in self._slotted.values() if not s.done]
         if not live:
             return {}
-        tokens = np.zeros((self.max_batch, 1), np.int32)
-        for s in live:
-            tokens[s.slot, 0] = s.tokens[-1]
-        arg, self.state = self.backend.decode(self.params, self.state, tokens)
-        self.steps += 1
-        out: dict[int, int] = {}
-        for s in live:
-            tok = int(arg[s.slot])
-            s.tokens.append(tok)
-            out[s.sid] = tok
-            self._touch(s)
-            if tok == self.eos_id or \
-                    s.prompt_len + len(s.tokens) >= self.max_seq - 1:
-                self.finish(s.sid)
-        self._sanitize_check()
+        with obs.span("engine.step"):
+            tokens = np.zeros((self.max_batch, 1), np.int32)
+            for s in live:
+                tokens[s.slot, 0] = s.tokens[-1]
+            arg, self.state = self.backend.decode(self.params, self.state,
+                                                  tokens)
+            self.steps += 1
+            out: dict[int, int] = {}
+            for s in live:
+                tok = int(arg[s.slot])
+                s.tokens.append(tok)
+                out[s.sid] = tok
+                self._touch(s)
+                if tok == self.eos_id or \
+                        s.prompt_len + len(s.tokens) >= self.max_seq - 1:
+                    self.finish(s.sid)
+            self._sanitize_check()
         return out
 
     def finish(self, sid: int) -> list[int]:
@@ -725,43 +748,46 @@ class Router:
         """The typed routing decision for one turn: which engine, which kind
         of hit, without side effects beyond what ``engine_for`` does (park a
         cluster-wide LRU victim to make room). ``follow_up`` executes it."""
-        eng = self.engine_for(sid)
-        if sid is None:
-            return RouteDecision(engine=eng, sid=-1, kind="new")
-        sess = eng.sessions.get(sid)
-        if sess is not None and not sess.done:
-            kind = "hit_live" if sess.slot is not None else "hit_parked"
-            return RouteDecision(engine=eng, sid=sid, kind=kind)
-        return RouteDecision(engine=eng, sid=sid, kind="migrate")
+        with obs.span("router.route", sid):
+            eng = self.engine_for(sid)
+            if sid is None:
+                return RouteDecision(engine=eng, sid=-1, kind="new")
+            sess = eng.sessions.get(sid)
+            if sess is not None and not sess.done:
+                kind = "hit_live" if sess.slot is not None else "hit_parked"
+                return RouteDecision(engine=eng, sid=sid, kind=kind)
+            return RouteDecision(engine=eng, sid=sid, kind="migrate")
 
     def follow_up(self, sid: int, history: list[int]) -> RouteDecision:
         """Route one follow-up turn end-to-end. On a locality hit the session
         is resumed in place (no prefill); otherwise it migrates: the old
         engine drops it and the target re-prefills ``history``. Returns a
         :class:`RouteDecision` — ``decision.sid`` changes on a migration."""
-        d = self.route(sid)
-        eng = d.engine
-        if d.kind in ("hit_live", "hit_parked"):
-            resumed = self.ensure_active(eng, sid)
+        with obs.span("router.follow_up", sid):
+            d = self.route(sid)
+            eng = d.engine
+            if d.kind in ("hit_live", "hit_parked"):
+                resumed = self.ensure_active(eng, sid)
+                self._sanitize_check()
+                return dataclasses.replace(d, resumed=resumed)
+            # migration: the cache holder (if any) discards its copy
+            for e in self.engines.values():
+                s = e.sessions.get(sid)
+                if s is not None and not s.done:
+                    e.finish(sid)
+            if sid in self._unhomed:
+                # a deferred failover session re-prefilled before any
+                # compatible engine joined: its parked-unhomed slice is
+                # superseded
+                del self._unhomed[sid]
+                if self.store.exists(_cache_name(sid)):
+                    self.store.delete(_cache_name(sid))
+            self.migrations += 1
+            if not eng.can_admit():  # engine_for made room already unless flat
+                raise RuntimeError("engine full")
+            new_sid = eng.submit(history)
             self._sanitize_check()
-            return dataclasses.replace(d, resumed=resumed)
-        # migration: the cache holder (if any) discards its copy
-        for e in self.engines.values():
-            s = e.sessions.get(sid)
-            if s is not None and not s.done:
-                e.finish(sid)
-        if sid in self._unhomed:
-            # a deferred failover session re-prefilled before any compatible
-            # engine joined: its parked-unhomed slice is superseded
-            del self._unhomed[sid]
-            if self.store.exists(_cache_name(sid)):
-                self.store.delete(_cache_name(sid))
-        self.migrations += 1
-        if not eng.can_admit():     # engine_for made room already unless flat
-            raise RuntimeError("engine full")
-        new_sid = eng.submit(history)
-        self._sanitize_check()
-        return dataclasses.replace(d, sid=new_sid, prefilled=True)
+            return dataclasses.replace(d, sid=new_sid, prefilled=True)
 
     # -------------------------------------------------------------- failover
     def fail_engine(self, node: int) -> FailoverReport:

@@ -156,7 +156,7 @@ def test_session_lifecycle_submit_park_resume_finish(setup):
     prefills_before = eng.prefills
     assert eng.resume(sid)
     assert eng.prefills == prefills_before
-    assert eng.rehydrates == 1
+    assert eng.resumes == 1
     assert store.stat(name).tier_on(0) == "hbm"
     for _ in range(2):
         eng.step()
