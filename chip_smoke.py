@@ -22,7 +22,8 @@ Phases on one chip:
   KV cache are compared with a cache-free forward pass over prompt+generated.
 * kernels — the Pallas flash and decode attention kernels, compiled for the
   chip (not interpreted), against ``kernels/ref.py`` at granite-3-2b head
-  geometry.
+  geometry; decode on a full-size (40, 8, 4096, 512) pool, called as the
+  model's decode step calls it.
 * executor — the 64-map/8-reduce workflow over 2 GiB of inputs with jitted
   task bodies, through ``compile_workflow`` -> ``ProactiveScheduler`` ->
   ``WorkflowExecutor`` with every node mapped to the chip; outputs must match
@@ -30,7 +31,8 @@ Phases on one chip:
 
 The serving and kernel comparisons also read planted faults (a stale or lost
 cache entry, another session's cache; a skipped k-block, an ignored mask or
-length) and fail unless each of them lies outside the bound.
+length, a left-out new token) and fail unless each of them lies outside the
+bound.
 
 With ``--chips 4``: a few steps of full-width, full-depth granite-3-2b
 training on a 2x2 (data, model) mesh, after the same steps at 2 layers have
@@ -306,7 +308,8 @@ def check_kernel(name: str, err: float, faults: dict[str, float]) -> None:
 
 def phase_kernels(seed: int, clock: CompileClock, *, B: int = 2,
                   S: int = 2048, Hq: int = 32, Hkv: int = 8, hd: int = 64,
-                  cache: int = 4096, decode_batch: int = 8) -> None:
+                  cache: int = 4096, decode_batch: int = 8,
+                  n_layers: int = 40) -> None:
     ks = jax.random.split(jax.random.PRNGKey(seed), 6)
     bf = jnp.bfloat16
 
@@ -333,27 +336,66 @@ def phase_kernels(seed: int, clock: CompileClock, *, B: int = 2,
         f"{n_c} compiles {c_s:.2f} s")
     check_kernel("flash_attention", row_rel_err(got, want), faults)
 
-    qd = normal(ks[3], (decode_batch, Hq, hd))
-    kc, vc = (normal(ks[4], (decode_batch, cache, Hkv, hd)),
-              normal(ks[5], (decode_batch, cache, Hkv, hd)))
-    lengths = np.random.default_rng(seed).integers(1, cache + 1, decode_batch)
-    lengths[0] = cache
+    # decode: the kernel on the pooled (L, B, S, Hkv*hd) cache, called as
+    # the model's decode step calls it, for one traced layer of the pool
+    C = Hkv * hd
+    kd = jax.random.split(ks[3], 5)
+    qd = normal(kd[0], (decode_batch, Hq, hd))
+    kp, vp = (normal(kd[1], (n_layers, decode_batch, cache, C)),
+              normal(kd[2], (n_layers, decode_batch, cache, C)))
+    kn, vn = (normal(kd[3], (decode_batch, C)),
+              normal(kd[4], (decode_batch, C)))
+    lengths = np.random.default_rng(seed).integers(1, cache, decode_batch)
+    lengths[0], lengths[1] = cache - 1, 0       # full, and an idle slot
     lengths = jnp.asarray(lengths, jnp.int32)
+    layer = jnp.int32(n_layers // 2)
+    decode = jax.jit(lambda *a: dec_k.decode_attention(*a, interpret=False))
     mark = len(clock.seconds)
-    got = dec_k.decode_attention(qd, kc, vc, lengths, interpret=False)
+    got = decode(qd, kp, vp, kn, vn, lengths, layer, jnp.int32(0))
     n_c, c_s = clock.since(mark)
-    bk = dec_k.DEFAULT_BK
+    bk = dec_k.block_size(cache)
+    bidx = jnp.arange(decode_batch)
+
+    def cache_with_token(at):
+        return [p[layer].at[bidx, at].set(new).reshape(decode_batch, cache,
+                                                       Hkv, hd)
+                for p, new in ((kp, kn), (vp, vn))]
+
     with jax.default_matmul_precision("highest"):
-        want = ref.decode_attention_ref(qd, kc, vc, lengths)
+        want = ref.decode_attention_ref(qd, *cache_with_token(lengths),
+                                        lengths + 1)
+        # the last live block skipped: the token sits where it starts
+        start = jnp.where(lengths > 0, (lengths - 1) // bk * bk, 0)
         faults = {
             "lengths ignored": row_rel_err(ref.decode_attention_ref(
-                qd, kc, vc, jnp.full_like(lengths, cache)), want),
+                qd, *cache_with_token(lengths),
+                jnp.full_like(lengths, cache)), want),
             "last k-block skipped": row_rel_err(ref.decode_attention_ref(
-                qd, kc, vc, jnp.maximum(lengths - bk, 1)), want)}
-    log(f"[kernels] decode_attention B={decode_batch} cache={cache} Hq={Hq} "
-        f"Hkv={Hkv} hd={hd} bf16: max abs err {abs_err(got, want):.5f}; "
-        f"{n_c} compiles {c_s:.2f} s")
+                qd, *cache_with_token(start), start + 1), want),
+            "new token left out": row_rel_err(ref.decode_attention_ref(
+                qd, kp[layer].reshape(decode_batch, cache, Hkv, hd),
+                vp[layer].reshape(decode_batch, cache, Hkv, hd),
+                jnp.maximum(lengths, 1)), want)}
+    log(f"[kernels] decode_attention pool L={n_layers} B={decode_batch} "
+        f"S={cache} Hq={Hq} Hkv={Hkv} hd={hd} bk={bk} bf16: max abs err "
+        f"{abs_err(got, want):.5f}; {n_c} compiles {c_s:.2f} s")
     check_kernel("decode_attention", row_rel_err(got, want), faults)
+
+    # the same call with a traced window, as a localglobal model's local
+    # layers make it (a quarter of the cache: gemma3's 1024 at 4096); most
+    # slots' lengths pass it, so the kernel reads from the window's first
+    # block to the last live one
+    window = cache // 4
+    got = decode(qd, kp, vp, kn, vn, lengths, layer, jnp.int32(window))
+    with jax.default_matmul_precision("highest"):
+        want = ref.decode_attention_ref(qd, *cache_with_token(lengths),
+                                        lengths + 1, window=window)
+        faults = {"window ignored": row_rel_err(ref.decode_attention_ref(
+            qd, *cache_with_token(lengths), lengths + 1), want)}
+    log(f"[kernels] decode_attention window={window}: "
+        f"{int((lengths > window).sum())} of {decode_batch} slots past it, "
+        f"max abs err {abs_err(got, want):.5f}")
+    check_kernel("decode_attention window", row_rel_err(got, want), faults)
 
 
 # --------------------------------------------------------------- executor

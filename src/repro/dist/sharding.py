@@ -174,11 +174,13 @@ def batch_specs(cfg: ModelConfig, batch: Pytree, mesh) -> Pytree:
 
 # ------------------------------------------------------- decode-state specs
 def _cache_spec(mesh, shape, *, b_dim: int | None, s_dim: int | None,
-                h_dim: int | None) -> P:
+                h_dim: int | None, heads: int | None = None) -> P:
     """The decode-cache rule, in priority order:
 
       1. heads (or the channel dim standing in for them) take "model" if they
-         divide it — heads-local attention, no cross-chip KV traffic;
+         divide it — heads-local attention, no cross-chip KV traffic. Where
+         the channel dim holds ``heads`` heads side by side, the heads must
+         divide it: a head is never split;
       2. batch takes the DP axes;
       3. the sequence dim sweeps up whatever is left ("model" first — the
          kv<model GQA fallback — then unused DP axes when batch=1).
@@ -187,7 +189,7 @@ def _cache_spec(mesh, shape, *, b_dim: int | None, s_dim: int | None,
     out: list = [None] * len(shape)
     tp = tp_axis(mesh)
     if h_dim is not None and tp is not None:
-        out[h_dim] = _fit(mesh, shape[h_dim], (tp,), used)
+        out[h_dim] = _fit(mesh, heads or shape[h_dim], (tp,), used)
     if b_dim is not None:
         out[b_dim] = _fit(mesh, shape[b_dim], dp_axes(mesh), used)
     if s_dim is not None:
@@ -198,6 +200,8 @@ def _cache_spec(mesh, shape, *, b_dim: int | None, s_dim: int | None,
 
 def decode_state_specs(cfg: ModelConfig, state: Pytree, mesh) -> Pytree:
     """Specs for a decode-state pytree (any family's ``init_decode_state``)."""
+    from repro.models.model import pooled_cache_axes
+    pooled = pooled_cache_axes(cfg)
 
     def spec_for(path, leaf):
         names = _leaf_names(path)
@@ -205,6 +209,8 @@ def decode_state_specs(cfg: ModelConfig, state: Pytree, mesh) -> Pytree:
         nd = leaf.ndim
         if name == "pos" or nd == 1:
             return _check(mesh, leaf.shape, (_dp_entry(mesh),))
+        if name in ("k", "v") and pooled is not None:
+            return _cache_spec(mesh, leaf.shape, **pooled)
         if name in ("k", "v", "attn_k", "attn_v", "xk", "xv"):
             # (L, B, S, Hkv, hd) — vlm stacks an extra group dim in front
             return _cache_spec(mesh, leaf.shape, b_dim=nd - 4, s_dim=nd - 3,

@@ -1,10 +1,10 @@
 """Pallas TPU kernels for the attention hot spots + pure-jnp oracles.
 
 flash_attention.py / decode_attention.py: pl.pallas_call + BlockSpec VMEM
-tiling; ops.py: jit wrappers; ref.py: oracles. Validated in interpret mode on
-CPU (TPU is the target, not the runtime).
+tiling; ops.py: the flash kernel's jit wrapper; ref.py: oracles. Validated in
+interpret mode on CPU (TPU is the target, not the runtime).
 """
 
-from repro.kernels.ops import attention_op, decode_attention_op, window_slice
+from repro.kernels.ops import attention_op
 
-__all__ = ["attention_op", "decode_attention_op", "window_slice"]
+__all__ = ["attention_op"]

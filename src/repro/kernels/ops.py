@@ -1,26 +1,20 @@
-"""Jit'd public wrappers around the Pallas kernels.
+"""Jit'd public wrapper around the flash-attention Pallas kernel.
 
-``attention_op`` / ``decode_attention_op`` pick the implementation:
-  * ``impl="pallas"``  — the TPU kernels (real hardware path),
-  * ``impl="interpret"`` — same kernels, interpret mode (CPU validation),
+``attention_op`` picks the implementation:
+  * ``impl="pallas"``  — the TPU kernel (real hardware path),
+  * ``impl="interpret"`` — same kernel, interpret mode (CPU validation),
   * ``impl="xla"``     — the pure-jnp reference (CPU container default; also
     what the dry-run lowers, since Pallas TPU kernels cannot compile for the
     host-CPU placeholder devices).
 
-``window_slice`` is the decode-side optimization used by sliding-window archs:
-instead of sweeping the whole cache and masking, slice the last ``window``
-entries around the current position (aligned down to the block size) so the
-kernel only streams live data — this converts the local-layer decode roofline
-term from O(S) to O(window).
+Decode attention has no such wrapper: the pooled decode step
+(``models.model``) calls ``decode_attention.decode_attention`` on a TPU and
+``layers.decode_attention`` elsewhere.
 """
 
 from __future__ import annotations
 
-import jax
-import jax.numpy as jnp
-
 from repro.kernels import ref
-from repro.kernels.decode_attention import decode_attention as _decode_pallas
 from repro.kernels.flash_attention import flash_attention as _flash_pallas
 
 IMPLS = ("xla", "pallas", "interpret")
@@ -36,36 +30,3 @@ def attention_op(q, k, v, *, causal: bool = True, window: int = 0,
     return _flash_pallas(q, k, v, causal=causal, window=window,
                          q_offset=q_offset, softmax_scale=softmax_scale,
                          interpret=(impl == "interpret"))
-
-
-def decode_attention_op(q, k_cache, v_cache, lengths, *, window: int = 0,
-                        softmax_scale: float | None = None,
-                        impl: str = "xla"):
-    if impl == "xla":
-        return ref.decode_attention_ref(q, k_cache, v_cache, lengths,
-                                        window=window,
-                                        softmax_scale=softmax_scale)
-    return _decode_pallas(q, k_cache, v_cache, lengths, window=window,
-                          softmax_scale=softmax_scale,
-                          interpret=(impl == "interpret"))
-
-
-def window_slice(cache: jax.Array, lengths: jax.Array, window: int,
-                 block: int = 512) -> tuple[jax.Array, jax.Array]:
-    """Slice the last ``window`` (block-aligned) cache entries per batch row.
-
-    cache: (B, S, H, hd); returns (sliced (B, W', H, hd), new lengths).
-    W' = window rounded up to ``block`` + one extra block of slack so the
-    slice start can be block-aligned (keeps DMA strides clean on TPU).
-    """
-    B, S, H, hd = cache.shape
-    Wp = min(S, ((window + block - 1) // block + 1) * block)
-    start = jnp.maximum(lengths - window, 0)
-    start = (start // block) * block                     # align down
-    start = jnp.clip(start, 0, S - Wp)                   # keep slice in bounds
-
-    def take(c, s):
-        return jax.lax.dynamic_slice(c, (s, 0, 0), (Wp, H, hd))
-
-    sliced = jax.vmap(take)(cache, start)
-    return sliced, lengths - start

@@ -19,7 +19,9 @@ Implementation notes
   * layers are stacked and driven by ``lax.scan`` (small HLO, fast compiles at
     61-100 layers) with per-layer remat (``nothing_saveable``) during training;
   * decode keeps KV/SSM caches in the scan *carry* and updates slices in place
-    (single cache buffer; pairs with buffer donation in the serve step);
+    (single cache buffer; pairs with buffer donation in the serve step). The
+    pooled ``dense``/``localglobal`` step instead reads its (L, B, S, Hkv*hd)
+    pool from outside the scan and writes the step's tokens after it;
   * architectures with periodic special layers (zamba2 shared attention,
     llama-vision cross-attention) scan over *groups* so special-layer params
     and caches have exact shapes (no dead weights);
@@ -199,7 +201,7 @@ def _dense_prefill(cfg: ModelConfig, params: Pytree, batch: Pytree,
     def step(h, pw):
         p, w = pw
         h, kv = _gqa_layer(cfg, p, h, positions, w, build_cache=max_seq)
-        return h, kv
+        return h, tuple(c.reshape(B, max_seq, -1) for c in kv)
 
     h, (ck, cv) = jax.lax.scan(step, h, (params["blocks"], windows))
     h = rms_norm(h, params["final_norm"], cfg.norm_eps)
@@ -209,47 +211,134 @@ def _dense_prefill(cfg: ModelConfig, params: Pytree, batch: Pytree,
 
 
 def _dense_decode_state(cfg: ModelConfig, batch: int, max_seq: int) -> Pytree:
+    """The pooled decode state: ``pos`` (B,) is each slot's cached length;
+    ``k``/``v`` are (L, B, S, Hkv*hd), the kv heads side by side in the
+    minor dimension (the layout ``kernels.decode_attention`` reads)."""
     dims = _dims(cfg)
-    shape = (cfg.n_layers, batch, max_seq, dims.n_kv_heads, dims.hd)
+    shape = (cfg.n_layers, batch, max_seq, dims.n_kv_heads * dims.hd)
     return {"pos": jnp.zeros((batch,), jnp.int32),
             "k": jnp.zeros(shape, _dtype(cfg)),
             "v": jnp.zeros(shape, _dtype(cfg))}
 
 
+_POOLED_FAMILIES = ("dense", "localglobal")   # decode reads a pooled cache
+
+
+def pooled_cache_axes(cfg: ModelConfig) -> dict | None:
+    """Where the pooled K/V cache keeps its axes, for ``dist.sharding``: the
+    slot (``b_dim``), the position (``s_dim``) and the channel (``h_dim``)
+    that holds ``heads`` kv heads side by side. None for a family whose
+    decode state is not pooled."""
+    if cfg.family not in _POOLED_FAMILIES:
+        return None
+    return {"b_dim": 1, "s_dim": 2, "h_dim": 3, "heads": cfg.n_kv_heads}
+
+
+def pooled_kv_blocks(cfg: ModelConfig, lengths, max_seq: int
+                     ) -> tuple[int, int] | None:
+    """Blocks of K (as many again of V) that one pooled decode step fetches
+    over its layers, each with its window, for these per-slot cached
+    lengths; and the blocks the pool holds over its layers. None for a
+    family whose decode state is not pooled."""
+    if cfg.family not in _POOLED_FAMILIES:
+        return None
+    from repro.kernels import decode_attention as kernel
+    windows, layers = np.unique(_windows(cfg), return_counts=True)
+    read = sum(int(n) * kernel.blocks_fetched(lengths, max_seq, window=int(w))
+               for w, n in zip(windows, layers))
+    per_slot = max_seq // kernel.block_size(max_seq)
+    return read, cfg.n_layers * len(lengths) * per_slot
+
+
+def _pooled_attention_impl() -> str:
+    """How the pooled decode step attends: the Pallas kernel ("pallas") on a
+    TPU backend, ``layers.decode_attention`` ("xla") elsewhere."""
+    return "pallas" if jax.default_backend() == "tpu" else "xla"
+
+
+def _pooled_kernel(cfg: ModelConfig, pool: jax.Array):
+    """The decode kernel for this pool, or None where the XLA path attends.
+
+    Under a mesh (``dist.hints.sharding_rules``) the kernel runs on each
+    device's shard of the pool, as ``dist.sharding.decode_state_specs``
+    places it: slots on the DP axes, kv heads on "model" where they divide
+    it. A pool sharded along its positions (kv heads that do not divide
+    "model", or one slot over DP) would need a softmax merged across chips,
+    so there the XLA path attends, in that same layout."""
+    impl = _pooled_attention_impl()
+    if impl == "xla":
+        return None
+    from repro.dist.hints import get_rules
+    from repro.dist.sharding import decode_state_specs
+    from repro.kernels.decode_attention import decode_attention
+    kernel = partial(decode_attention, interpret=impl == "interpret")
+    rules = get_rules()
+    if rules is None:
+        return kernel
+    _, b, s, c = decode_state_specs(cfg, {"k": pool}, rules["mesh"])["k"]
+    if s is not None:
+        return None
+    P = jax.sharding.PartitionSpec
+    kv = P(None, b, None, c)
+    return jax.shard_map(
+        kernel, mesh=rules["mesh"],
+        in_specs=(P(b, c, None), kv, kv, P(b, c), P(b, c), P(b), P(), P()),
+        out_specs=P(b, c, None), check_vma=False)
+
+
 def _dense_decode(cfg: ModelConfig, params: Pytree, state: Pytree,
                   tokens: jax.Array):
+    """One token for every slot. The scan reads the pool and never writes
+    it: each layer attends over the slot's cached positions plus the token's
+    own key and value, and emits them; after the scan, one update a slot
+    writes the L layers' tokens into the pool (in place where the caller
+    donates it). A slot whose token is negative holds no session: its
+    cached length is set to 0 first, so that the step reads none of its
+    cache."""
     dims = _dims(cfg)
     B = tokens.shape[0]
-    pos = state["pos"]                                     # (B,)
-    h = _embed(params, tokens)                             # (B,1,d)
+    pos = jnp.where(tokens[:, 0] >= 0, state["pos"], 0)   # (B,)
+    ck, cv = state["k"], state["v"]                        # (L, B, S, C)
+    S, C = ck.shape[2], ck.shape[3]
+    h = _embed(params, jnp.maximum(tokens, 0))             # (B,1,d)
     windows = jnp.asarray(_windows(cfg))
     bidx = jnp.arange(B)
+    kernel = _pooled_kernel(cfg, ck)
 
-    def step(carry, x):
-        h, ck, cv = carry
+    def attend(q, k_new, v_new, li, w):
+        if kernel is None:
+            def layer(c, new):
+                c = jax.lax.dynamic_index_in_dim(c, li, 0, keepdims=False)
+                return c.at[bidx, pos].set(new).reshape(
+                    B, S, dims.n_kv_heads, dims.hd)
+            return decode_attention(q, layer(ck, k_new), layer(cv, v_new),
+                                    q_pos=pos, window=w)
+        return kernel(q[:, 0], ck, cv, k_new, v_new, pos, li, w)[:, None]
+
+    def step(h, x):
         p, li, w = x
         hn = rms_norm(h, p["ln1"], cfg.norm_eps)
         q = (hn @ p["attn"]["wq"]).reshape(B, 1, dims.n_heads, dims.hd)
         k = (hn @ p["attn"]["wk"]).reshape(B, 1, dims.n_kv_heads, dims.hd)
-        v = (hn @ p["attn"]["wv"]).reshape(B, 1, dims.n_kv_heads, dims.hd)
+        v = (hn @ p["attn"]["wv"]).reshape(B, C).astype(cv.dtype)
         q = apply_rope(q, pos[:, None], cfg.rope_theta)
-        k = apply_rope(k, pos[:, None], cfg.rope_theta)
-        k_l = jax.lax.dynamic_index_in_dim(ck, li, 0, keepdims=False)
-        v_l = jax.lax.dynamic_index_in_dim(cv, li, 0, keepdims=False)
-        k_l = k_l.at[bidx, pos].set(k[:, 0])
-        v_l = v_l.at[bidx, pos].set(v[:, 0])
-        o = decode_attention(q, k_l, v_l, q_pos=pos, window=w)
+        k = apply_rope(k, pos[:, None], cfg.rope_theta).reshape(
+            B, C).astype(ck.dtype)
+        o = attend(q, k, v, li, w)
         h = h + o.reshape(B, 1, dims.n_heads * dims.hd) @ p["attn"]["wo"]
         h = h + mlp_block(p["mlp"], rms_norm(h, p["ln2"], cfg.norm_eps))
-        ck = jax.lax.dynamic_update_index_in_dim(ck, k_l, li, 0)
-        cv = jax.lax.dynamic_update_index_in_dim(cv, v_l, li, 0)
-        return (h, ck, cv), None
+        return h, (k, v)
 
-    (h, ck, cv), _ = jax.lax.scan(
-        step, (h, state["k"], state["v"]),
-        (params["blocks"], jnp.arange(cfg.n_layers), windows))
+    h, (nk, nv) = jax.lax.scan(
+        step, h, (params["blocks"], jnp.arange(cfg.n_layers), windows))
     h = rms_norm(h, params["final_norm"], cfg.norm_eps)
     logits = _head(cfg, params, h)
+    # one (L, 1, 1, C) update per slot: a scatter over (slot, position)
+    # would have XLA relayout the whole pool around it
+    for b in range(B):
+        at = (0, b, pos[b], 0)
+        ck = jax.lax.dynamic_update_slice(ck, nk[:, b, None, None], at)
+        cv = jax.lax.dynamic_update_slice(cv, nv[:, b, None, None], at)
     return logits, {"pos": pos + 1, "k": ck, "v": cv}
 
 
@@ -1222,6 +1311,12 @@ def init_decode_state(cfg: ModelConfig, batch: int, max_seq: int) -> Pytree:
 
 def decode_step(cfg: ModelConfig, params: Pytree, state: Pytree,
                 tokens: jax.Array):
+    """One token for every slot of ``state``; ``tokens`` (B, 1). A negative
+    token marks a slot that holds no session, whose output is to be
+    discarded; the pooled families (``dense``, ``localglobal``) read none of
+    its cache."""
+    if cfg.family not in _POOLED_FAMILIES:
+        tokens = jnp.maximum(tokens, 0)
     return _FAMILY[cfg.family][4](cfg, params, state, tokens)
 
 

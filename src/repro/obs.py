@@ -1,4 +1,4 @@
-"""Spans and a compile counter, owned by the program.
+"""Spans, counters and a compile counter, owned by the program.
 
 ``span(name, key=None)`` marks a stretch of host work inside one layer of the
 program (``engine.step``, ``store.get``, ``task.body``, ...). It records only
@@ -10,8 +10,11 @@ task id) as metadata, and it appends a :class:`Record` to a bounded buffer
 in memory. With no profiler collecting, a span costs one check and is a
 shared null context.
 
-:func:`summary` reduces the buffer per span name; :func:`reset` empties it.
-The profile is the only exporter.
+``count(name, n)`` adds ``n`` to a named counter (``engine.kv_blocks_read``,
+...). Like a span, it records only while a profiler collects.
+
+:func:`summary` reduces the buffer per span name and gives the counters;
+:func:`reset` empties both. The profile is the only exporter of spans.
 
 The compile counter listens for JAX's backend-compile event (a compile, or a
 load from the persistent compile cache) from import on, whether or not a
@@ -29,8 +32,8 @@ from typing import Any, Callable, NamedTuple
 
 import jax
 
-__all__ = ["Record", "span", "traced", "summary", "reset", "records",
-           "compiles"]
+__all__ = ["Record", "span", "traced", "count", "summary", "reset",
+           "records", "compiles"]
 
 PREFIX = "repro."
 MAX_RECORDS = 1 << 16
@@ -57,6 +60,7 @@ class Record(NamedTuple):
 _records: collections.deque[tuple] = collections.deque(maxlen=MAX_RECORDS)
 _compiles: collections.deque[tuple[float, str, float]] = \
     collections.deque(maxlen=MAX_RECORDS)
+_counters: collections.Counter[str] = collections.Counter()
 _local = threading.local()
 _names: dict[str, tuple[str, str]] = {}   # name -> (annotation name, layer)
 
@@ -159,14 +163,22 @@ def traced(name: str) -> Callable:
     return wrap
 
 
+def count(name: str, n: int = 1) -> None:
+    """Add ``n`` to the counter ``name`` while a profiler collects."""
+    if _collecting():
+        _counters[name] += n
+
+
 def records() -> list[Record]:
     """The recorded spans, oldest first (the last ``MAX_RECORDS``)."""
     return [Record(*r) for r in _records]
 
 
 def reset() -> None:
-    """Forget every recorded span (the compile counter keeps counting)."""
+    """Forget every recorded span and counter (the compile counter keeps
+    counting)."""
     _records.clear()
+    _counters.clear()
 
 
 def summary() -> dict:
@@ -180,6 +192,7 @@ def summary() -> dict:
       same layer on their thread, so that nested calls count once;
     * ``start_s``: ``time.perf_counter()`` at the earliest recorded start,
       None when nothing is recorded;
+    * ``counters``: per counter name, its total (:func:`count`);
     * ``compiles``: per function name, ``count`` and ``seconds`` of every
       compile event seen (:func:`compiles`).
     """
@@ -199,7 +212,7 @@ def summary() -> dict:
             lay["total_s"] += r.end - r.start
     return {"spans": spans, "layers": layers,
             "start_s": min((r.start for r in recs), default=None),
-            "compiles": compiles()}
+            "counters": dict(_counters), "compiles": compiles()}
 
 
 def compiles(before: float | None = None) -> dict[str, dict]:

@@ -126,7 +126,9 @@ class JaxComputeBackend:
         def decode_step(p, s, t):
             return M.decode_step(cfg, p, s, t)
 
-        self._decode = jax.jit(decode_step)
+        # the pooled state is donated: the step writes its tokens into the
+        # pool in place, and the caller holds only the state it gets back
+        self._decode = jax.jit(decode_step, donate_argnums=(1,))
         self._prefill1 = jax.jit(prefill)
         self._template: Pytree | None = None
 
@@ -169,7 +171,9 @@ class JaxComputeBackend:
 
     def decode(self, params: Pytree, state: Pytree,
                tokens: np.ndarray) -> tuple[np.ndarray, Pytree]:
-        """One pooled decode step; returns (argmax token per slot, state)."""
+        """One pooled decode step; returns (argmax token per slot, state).
+        ``tokens`` (B, 1) holds -1 in a slot that holds no session. The
+        state is donated: only the returned one may be read."""
         logits, state = self._decode(params, state, jnp.asarray(tokens))
         arg = jnp.argmax(logits[:, -1], axis=-1)
         with obs.span("engine.step.sync"):
@@ -510,9 +514,20 @@ class ServingEngine:
         if not live:
             return {}
         with obs.span("engine.step"):
-            tokens = np.zeros((self.max_batch, 1), np.int32)
+            # -1: a slot that holds no session (the step reads none of its
+            # cache, and its token is discarded)
+            tokens = np.full((self.max_batch, 1), -1, np.int32)
+            lengths = [0] * self.max_batch
             for s in live:
                 tokens[s.slot, 0] = s.tokens[-1]
+                lengths[s.slot] = s.prompt_len + len(s.tokens) - 1
+            blocks = None if self.cfg is None else \
+                M.pooled_kv_blocks(self.cfg, lengths, self.max_seq)
+            if blocks is not None:
+                obs.count("engine.kv_blocks_read", blocks[0])
+                obs.count("engine.kv_blocks_pool", blocks[1])
+            # the state handed to decode is donated: only the returned one
+            # may be read
             arg, self.state = self.backend.decode(self.params, self.state,
                                                   tokens)
             self.steps += 1
@@ -559,7 +574,9 @@ def _write_slot(pooled: Pytree, single: Pytree, slot: int) -> Pytree:
 
     def ins(p, s):
         if p.shape == s.shape:   # max_batch == 1: the single state IS the slot
-            return s.astype(p.dtype)
+            # a copy: the pooled state is donated to decode, and ``single``
+            # may still be held (a stored replica of a parked slice)
+            return jnp.array(s, dtype=p.dtype, copy=True)
         axis = next(i for i, (a, b) in enumerate(zip(p.shape, s.shape))
                     if a != b and b == 1)
         idx = [slice(None)] * p.ndim

@@ -83,9 +83,38 @@ def run_three_tasks():
 def test_no_profiler_leaves_no_records(model):
     serve_one_session(*model)
     run_three_tasks()
+    obs.count("engine.kv_blocks_read", 3)
     assert obs.records() == []
     s = obs.summary()
     assert s["spans"] == {} and s["layers"] == {} and s["start_s"] is None
+    assert s["counters"] == {}
+
+
+def test_counters_add_while_a_profiler_collects_and_reset(tmp_path):
+    with jax.profiler.trace(str(tmp_path)):
+        obs.count("a", 2)
+        obs.count("a")
+        obs.count("b", 0)
+    obs.count("a", 100)                 # after the profiler: not recorded
+    assert obs.summary()["counters"] == {"a": 3, "b": 0}
+    obs.reset()
+    assert obs.summary()["counters"] == {}
+
+
+def test_engine_counts_the_kv_blocks_a_step_reads(model, tmp_path):
+    """At max_seq 1024 a block is 512 positions: in each layer a slot of
+    cached length 3 reads one block, one of 600 reads two, an idle slot one
+    (its first index is fetched); the pool holds 3 x 2 blocks a layer."""
+    cfg, params = model
+    eng = ServingEngine(cfg, params, max_batch=3, max_seq=1024)
+    eng.submit([1, 2, 3])
+    eng.submit(list(range(1, 601)))
+    with jax.profiler.trace(str(tmp_path)):
+        eng.step()                      # cached lengths 3, 600 and idle
+        eng.step()                      # 4, 601
+    L = cfg.n_layers
+    assert obs.summary()["counters"] == {"engine.kv_blocks_read": 2 * 4 * L,
+                                         "engine.kv_blocks_pool": 2 * 6 * L}
 
 
 def test_nested_spans_carry_parents_self_time_and_keys(tmp_path):

@@ -168,6 +168,75 @@ def test_session_lifecycle_submit_park_resume_finish(setup):
     assert store.tier_report()["hbm"]["resident_bytes"] == 0.0
 
 
+def test_decode_state_is_donated_and_not_read_again(setup):
+    """The pooled state handed to decode is donated: its buffers are gone
+    after the step (a read would raise), the engine holds only the state it
+    got back, and decoding goes on through park and resume."""
+    cfg, params = setup
+    store = LocStore(1, hierarchy=tiered_hierarchy())
+    eng = ServingEngine(cfg, params, max_batch=2, max_seq=64, store=store)
+    handed = []
+    decode = eng.backend.decode
+
+    def recording(p, state, tokens):
+        handed.append(state)
+        return decode(p, state, tokens)
+
+    eng.backend.decode = recording
+    sid = eng.submit([5, 6, 7])
+    eng.step()
+    eng.park(sid)
+    eng.resume(sid)
+    eng.step()
+    assert len(handed) == 2
+    for state in handed:
+        assert all(leaf.is_deleted() for leaf in jax.tree.leaves(state))
+        assert not any(a is b for a, b in zip(jax.tree.leaves(state),
+                                              jax.tree.leaves(eng.state)))
+    assert not any(leaf.is_deleted() for leaf in jax.tree.leaves(eng.state))
+    assert len(eng.sessions[sid].tokens) == 3
+
+
+def test_single_slot_write_copies_the_stored_slice(setup):
+    """With one slot the written state is the slot: a copy, so that
+    donating it to decode leaves the stored slice readable."""
+    cfg, params = setup
+    from repro.models import init_decode_state
+    pooled = init_decode_state(cfg, 1, 32)
+    batch = {"tokens": jnp.asarray([[3, 1, 4]], jnp.int32)}
+    batch["labels"] = batch["tokens"]
+    _, single = prefill(cfg, params, batch, 32)
+    merged = _write_slot(pooled, single, 0)
+    for a, b in zip(jax.tree.leaves(merged), jax.tree.leaves(single)):
+        assert a is not b
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    jax.jit(lambda s: s, donate_argnums=0)(merged)
+    assert not any(leaf.is_deleted() for leaf in jax.tree.leaves(single))
+
+
+def test_idle_slots_read_no_cache(setup):
+    """A slot that holds no session goes to the step with token -1, and
+    the step sets its cached length to 0 before it attends, so it reads
+    none of the slot's cache however long it idles; live slots decode as a
+    control that never shared the pool."""
+    cfg, params = setup
+    eng = ServingEngine(cfg, params, max_batch=3, max_seq=64)
+    control = ServingEngine(cfg, params, max_batch=3, max_seq=64)
+    gone = eng.submit([9, 9, 9, 9, 9])
+    sid = eng.submit([1, 2, 3])
+    c_sid = control.submit([1, 2, 3])
+    idle_slot = eng.sessions[gone].slot
+    eng.step()
+    eng.finish(gone)
+    for _ in range(6):
+        eng.step()
+        control.step()
+    pos = np.asarray(eng.state["pos"])
+    assert pos[idle_slot] == 1         # cleared to 0, then one step
+    assert pos[eng.sessions[sid].slot] == 3 + 7
+    assert eng.sessions[sid].tokens[:7] == control.sessions[c_sid].tokens[:7]
+
+
 def test_park_idle_sweep(setup):
     cfg, params = setup
     probe = ServingEngine(cfg, params, max_batch=2, max_seq=64)

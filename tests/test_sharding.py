@@ -81,15 +81,15 @@ class TestDecodeStateSpecs:
         cfg = get_config("granite-3-2b")     # kv=8 < model=16
         state, _ = decode_specs_for(cfg, SHAPES["decode_32k"])
         specs = shd.decode_state_specs(cfg, state, mesh1())
-        k = tuple(specs["k"])                # (L, B, S, kv, hd)
+        k = tuple(specs["k"])                # (L, B, S, kv*hd)
         assert k[1] == "data" and k[2] == "model" and k[3] is None
 
     def test_gqa_kv16_shards_heads(self):
         cfg = get_config("gemma3-27b")       # kv=16 == model
         state, _ = decode_specs_for(cfg, SHAPES["decode_32k"])
         specs = shd.decode_state_specs(cfg, state, mesh1())
-        k = tuple(specs["k"])
-        assert k[3] == "model" and k[1] == "data"
+        k = tuple(specs["k"])                # 16 heads of 128 lanes
+        assert k[3] == "model" and k[1] == "data" and k[2] is None
 
     def test_long_500k_batch1_seq_takes_dp(self):
         cfg = get_config("gemma3-27b")

@@ -10,6 +10,9 @@ in ``conftest.py`` or in a ``parametrize``/``skipif`` argument): only one
 process at a time may load libtpu, and every test worker imports this file.
 """
 
+import dataclasses
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -76,20 +79,31 @@ def test_flash_attention_compiles_for_v5e(one_chip, no_persistent_cache,
                          ids=["bf16", "f32"])
 def test_decode_attention_compiles_for_v5e(one_chip, no_persistent_cache,
                                            dtype):
-    B, S = 8, 4096
+    """The kernel on granite-3-2b's pool, (L, B, S, Hkv*hd), with the layer
+    and window traced as the model's scan traces them."""
+    L, B, S = 40, 8, 4096
     args = (_shape(one_chip, (B, HQ, HD), dtype),
-            _shape(one_chip, (B, S, HKV, HD), dtype),
-            _shape(one_chip, (B, S, HKV, HD), dtype),
-            _shape(one_chip, (B,), jnp.int32))
+            _shape(one_chip, (L, B, S, HKV * HD), dtype),
+            _shape(one_chip, (L, B, S, HKV * HD), dtype),
+            _shape(one_chip, (B, HKV * HD), dtype),
+            _shape(one_chip, (B, HKV * HD), dtype),
+            _shape(one_chip, (B,), jnp.int32),
+            _shape(one_chip, (), jnp.int32),
+            _shape(one_chip, (), jnp.int32))
     compiled = jax.jit(
-        lambda q, k, v, n: decode_attention(q, k, v, n, interpret=False)
+        lambda *a: decode_attention(*a, interpret=False)
     ).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_granite_decode_step_fits_one_v5e(one_chip, no_persistent_cache):
+def test_granite_decode_step_fits_one_v5e(one_chip, no_persistent_cache,
+                                          monkeypatch):
     """Full-width, full-depth granite-3-2b decode over an 8 x 4096 KV pool —
-    the serving path ``chip_smoke.py`` drives — fits one chip's HBM."""
+    the serving path ``chip_smoke.py`` drives, as the engine jits it (the
+    Pallas kernel, the state donated) — fits one chip's HBM. The pool is
+    read and written where it lies: its device bytes are its logical bytes,
+    the output pool aliases the input, and no op copies the whole pool."""
+    monkeypatch.setattr(M, "_pooled_attention_impl", lambda: "pallas")
     cfg = get_config("granite-3-2b")
 
     def on_chip(tree):
@@ -99,20 +113,75 @@ def test_granite_decode_step_fits_one_v5e(one_chip, no_persistent_cache):
         lambda: M.init_params(cfg, jax.random.PRNGKey(0))))
     state = on_chip(jax.eval_shape(lambda: M.init_decode_state(cfg, 8, 4096)))
     tokens = _shape(one_chip, (8, 1), jnp.int32)
-    compiled = jax.jit(lambda p, s, t: M.decode_step(cfg, p, s, t)).lower(
+    compiled = jax.jit(lambda p, s, t: M.decode_step(cfg, p, s, t),
+                       donate_argnums=(1,)).lower(
         params, state, tokens).compile()
     ma = compiled.memory_analysis()
     used = ma.argument_size_in_bytes + ma.temp_size_in_bytes
+    logical = sum(x.size * x.dtype.itemsize
+                  for x in jax.tree.leaves((params, state)))
+    pool = state["k"].size * state["k"].dtype.itemsize       # 1.34 GB
     assert ma.argument_size_in_bytes > 7e9        # 5.3 GB params + 2.7 GB KV
+    assert abs(ma.argument_size_in_bytes - logical) < 1 << 20  # no padding
+    assert ma.alias_size_in_bytes >= 2 * pool
+    assert ma.temp_size_in_bytes < pool // 100
     assert used < V5E_HBM_BYTES
+    txt = compiled.as_text()
+    assert "tpu_custom_call" in txt
+    assert not re.search(r"= bf16\[40,8,4096,512\]\S* copy\(", txt)
+
+
+def test_granite_decode_step_shards_over_v5e_2x2(topo, no_persistent_cache,
+                                                monkeypatch):
+    """The pooled decode step with the Pallas kernel on a 2x2 (data, model)
+    mesh, at full width and 2 layers: the kernel runs on each chip's shard
+    of the pool (slots on data, kv heads on model), so no collective moves
+    the pool (a Mosaic kernel left to the partitioner does not compile),
+    and the donated pool stays in place. ``max_seq`` 3072 is a length that
+    no weight has, so a collective with that dimension would be the pool's."""
+    import collections
+
+    import numpy as np
+    from jax.sharding import AxisType, Mesh, PartitionSpec as P
+
+    from repro.dist import sharding as shd
+    from repro.dist.hints import sharding_rules
+
+    monkeypatch.setattr(M, "_pooled_attention_impl", lambda: "pallas")
+    cfg = dataclasses.replace(get_config("granite-3-2b"), n_layers=2)
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
+    B, S = 8, 3072
+    params = jax.eval_shape(lambda: M.init_params(cfg, jax.random.PRNGKey(0)))
+    state = jax.eval_shape(lambda: M.init_decode_state(cfg, B, S))
+    tokens = jax.ShapeDtypeStruct((B, 1), jnp.int32)
+    st_specs = shd.decode_state_specs(cfg, state, mesh)
+    assert st_specs["k"] == P(None, "data", None, "model")
+    st_sh = shd.named(mesh, st_specs)
+    step = jax.jit(lambda p, s, t: M.decode_step(cfg, p, s, t),
+                   in_shardings=(shd.named(mesh, shd.param_specs(cfg, params,
+                                                                 mesh)),
+                                 st_sh, shd.named(mesh, shd.batch_specs(
+                                     cfg, {"t": tokens}, mesh))["t"]),
+                   out_shardings=(None, st_sh), donate_argnums=(1,))
+    with sharding_rules(mesh):
+        compiled = step.lower(params, state, tokens).compile()
+    txt = compiled.as_text()
+    assert "tpu_custom_call" in txt
+    moved = collections.Counter(
+        m.group(2) for m in re.finditer(
+            r"= (.*?) (all-gather|all-to-all|collective-permute-start|"
+            r"all-reduce|reduce-scatter)\(", txt)
+        if re.search(rf"\[[0-9,]*\b{S}\b", m.group(1)))
+    assert not moved, moved
+    shard = state["k"].size * state["k"].dtype.itemsize // 4
+    assert compiled.memory_analysis().alias_size_in_bytes >= 2 * shard
 
 
 def test_granite_train_step_shards_over_v5e_2x2(topo, no_persistent_cache):
     """The four-chip path of ``chip_smoke.py --chips 4`` at full width and
     2 layers: the sharded step compiles for a 2x2 (data, model) mesh, splits
     the parameters and moments four ways, and needs collectives."""
-    import dataclasses
-
     import numpy as np
     from jax.sharding import AxisType, Mesh
 
