@@ -11,8 +11,9 @@ Families:
               attention block)
   rwkv      — rwkv6-1.6b (attn-free, data-dependent decay)
   encdec    — whisper-medium (audio frontend stubbed to frame embeddings)
-  moe       — deepseek-v3-671b (MLA + 1 shared/256 routed top-8 + MTP),
-              arctic-480b (dense-residual + 128 routed top-2)
+  moe       — deepseek-v3-671b (MLA with YaRN rope + 1 shared/256 routed
+              top-8 under a sigmoid, group-limited router + MTP),
+              arctic-480b (dense-residual + 128 routed top-2, softmax router)
   vlm       — llama-3.2-vision-90b (cross-attention image layers; patch
               embeddings stubbed)
 """
@@ -47,6 +48,23 @@ class MLAConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class YaRN:
+    """YaRN rope scaling (Peng et al., arXiv:2309.00071) as DeepSeek-V3
+    publishes it: rotary frequencies interpolated by ``factor`` outside the
+    band that ``beta_fast``/``beta_slow`` rotations over
+    ``original_max_position`` mark, cos and sin times
+    ``mscale(factor, mscale) / mscale(factor, mscale_all_dim)``, and the
+    softmax scale times ``mscale(factor, mscale_all_dim) ** 2``."""
+
+    factor: float = 40.0
+    original_max_position: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 1.0
+
+
+@dataclasses.dataclass(frozen=True)
 class ModelConfig:
     name: str
     family: Family
@@ -62,6 +80,7 @@ class ModelConfig:
     sliding_window: int = 0              # gemma3 local window (0 = none)
     global_every: int = 0                # gemma3: 1 global per this many layers
     rope_theta: float = 1e4
+    rope_scaling: YaRN | None = None     # deepseek-v3: YaRN (MLA rope part)
 
     # -- MoE --------------------------------------------------------------------
     n_experts: int = 0
@@ -70,8 +89,20 @@ class ModelConfig:
     n_shared_experts: int = 0            # deepseek shared expert(s)
     dense_residual: bool = False         # arctic: dense FFN in parallel w/ MoE
     first_dense_layers: int = 0          # deepseek: leading dense layers
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25        # training dispatch only
     router_aux_weight: float = 0.001
+    # the router: "softmax" top-k renormalised (arctic), or "sigmoid"
+    # (deepseek-v3: experts chosen by score + a per-expert bias among the
+    # top ``topk_groups`` of ``n_groups`` groups, weighted by the chosen
+    # scores normalised, times ``routed_scale``)
+    router_score: Literal["softmax", "sigmoid"] = "softmax"
+    n_groups: int = 1
+    topk_groups: int = 1
+    routed_scale: float = 1.0
+    # expert parallelism: this layer holds routed experts
+    # [ep_rank * n_experts / ep_size, ...) of the n_experts it routes over
+    ep_size: int = 1
+    ep_rank: int = 0
 
     # -- MLA / MTP ----------------------------------------------------------------
     mla: MLAConfig | None = None
@@ -110,6 +141,12 @@ class ModelConfig:
         return self.n_experts > 0
 
     @property
+    def experts_held(self) -> tuple[int, int]:
+        """(first, count) of the routed experts this layer holds."""
+        n = self.n_experts // self.ep_size
+        return self.ep_rank * n, n
+
+    @property
     def is_attention_free(self) -> bool:
         return self.family == "rwkv"
 
@@ -127,6 +164,11 @@ class ModelConfig:
         if self.is_moe:
             assert 0 < self.experts_per_token <= self.n_experts
             assert self.moe_d_ff > 0
+            assert self.n_experts % self.ep_size == 0
+            assert 0 <= self.ep_rank < self.ep_size
+            assert self.n_experts % self.n_groups == 0
+            assert self.experts_per_token <= self.topk_groups * (
+                self.n_experts // self.n_groups)
         if self.family == "localglobal":
             assert self.sliding_window > 0 and self.global_every > 0
         if self.family == "hybrid":

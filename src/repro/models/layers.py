@@ -13,6 +13,7 @@ statistics in f32 — the standard TPU mixed-precision recipe.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any
 
 import jax
@@ -45,18 +46,52 @@ def rms_norm(x: jax.Array, gamma: jax.Array, eps: float = 1e-6) -> jax.Array:
 
 
 # --------------------------------------------------------------------- rope
-def rope_frequencies(hd: int, theta: float) -> jax.Array:
-    """Inverse frequencies for the even half of the head dim (f32)."""
+def _yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_softmax_factor(yarn) -> float:
+    """What YaRN multiplies the attention softmax scale by (1 without it)."""
+    if yarn is None or not yarn.mscale_all_dim:
+        return 1.0
+    return _yarn_mscale(yarn.factor, yarn.mscale_all_dim) ** 2
+
+
+def rope_frequencies(hd: int, theta: float, yarn=None) -> jax.Array:
+    """Inverse frequencies for the even half of the head dim (f32). With
+    ``yarn`` (``configs.base.YaRN``), those whose wavelength exceeds the
+    original context are divided by its factor, with a linear ramp between
+    the ``beta_fast`` and ``beta_slow`` correction dimensions."""
     half = hd // 2
-    return 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+    inv = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+    if yarn is None:
+        return inv
+
+    def dim(rotations):
+        return (hd * math.log(yarn.original_max_position
+                              / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    lo = max(math.floor(dim(yarn.beta_fast)), 0)
+    hi = min(math.ceil(dim(yarn.beta_slow)), hd - 1)
+    ramp = np.clip((np.arange(half) - lo) / max(hi - lo, 1e-3), 0, 1)
+    keep = jnp.asarray(1.0 - ramp, jnp.float32)     # 1: not interpolated
+    return inv / yarn.factor * (1 - keep) + inv * keep
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """Rotate (…, S, H, hd) by per-position angles. ``positions``: (…, S)."""
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               yarn=None) -> jax.Array:
+    """Rotate (…, S, H, hd) by per-position angles. ``positions``: (…, S).
+    Pairs are the two halves of each head; ``yarn`` scales as
+    :func:`rope_frequencies` says, and cos and sin by its mscale ratio."""
     hd = x.shape[-1]
-    inv = rope_frequencies(hd, theta)                       # (hd/2,)
+    inv = rope_frequencies(hd, theta, yarn)                 # (hd/2,)
     ang = positions[..., :, None].astype(jnp.float32) * inv  # (..., S, hd/2)
     sin, cos = jnp.sin(ang)[..., None, :], jnp.cos(ang)[..., None, :]
+    if yarn is not None:
+        m = (_yarn_mscale(yarn.factor, yarn.mscale)
+             / _yarn_mscale(yarn.factor, yarn.mscale_all_dim))
+        sin, cos = sin * m, cos * m
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)

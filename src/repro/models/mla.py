@@ -5,6 +5,12 @@ Train/prefill: the latent ``c_kv`` is expanded to per-head keys/values
 folded through ``W_uk`` into latent space so the per-token cache is only
 ``kv_lora_rank + rope_dim`` floats (the whole point of MLA: a 576-wide cache
 instead of H*(192+128)), and attention runs directly against the latent cache.
+
+The rope part of queries and keys rotates the two halves of its 64 values
+(``layers.apply_rope``), with YaRN where the config has it; the softmax scale
+is ``qk_head_dim ** -0.5`` times YaRN's mscale squared. DeepSeek's published
+weights pair rope values interleaved, (0, 1), (2, 3), ...: a server folds
+that permutation into the rope columns of ``wq_b`` and ``wkv_a``.
 """
 
 from __future__ import annotations
@@ -17,7 +23,7 @@ import numpy as np
 
 from repro.configs.base import MLAConfig, ModelConfig
 from repro.models.layers import (NEG_INF, apply_rope, attention, init_linear,
-                                 rms_norm)
+                                 rms_norm, yarn_softmax_factor)
 
 Pytree = Any
 
@@ -47,7 +53,7 @@ def _queries(cfg: ModelConfig, p: Pytree, x: jax.Array, positions: jax.Array):
     cq = rms_norm(x @ p["wq_a"], p["q_norm"], cfg.norm_eps)
     q = (cq @ p["wq_b"]).reshape(B, S, H, m.qk_head_dim)
     q_nope, q_rope = jnp.split(q, [m.qk_nope_head_dim], axis=-1)
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = apply_rope(q_rope, positions, cfg.rope_theta, cfg.rope_scaling)
     return q_nope, q_rope
 
 
@@ -56,9 +62,13 @@ def _latents(cfg: ModelConfig, p: Pytree, x: jax.Array, positions: jax.Array):
     kv_a = x @ p["wkv_a"]
     c_kv, k_rope = jnp.split(kv_a, [m.kv_lora_rank], axis=-1)
     c_kv = rms_norm(c_kv, p["kv_norm"], cfg.norm_eps)
-    k_rope = apply_rope(k_rope[:, :, None, :], positions,
-                        cfg.rope_theta)[:, :, 0, :]
+    k_rope = apply_rope(k_rope[:, :, None, :], positions, cfg.rope_theta,
+                        cfg.rope_scaling)[:, :, 0, :]
     return c_kv, k_rope                        # (B,S,kv_lora), (B,S,rope)
+
+
+def softmax_scale(cfg: ModelConfig) -> float:
+    return cfg.mla.qk_head_dim ** -0.5 * yarn_softmax_factor(cfg.rope_scaling)
 
 
 def mla_attention(cfg: ModelConfig, p: Pytree, x: jax.Array,
@@ -76,56 +86,55 @@ def mla_attention(cfg: ModelConfig, p: Pytree, x: jax.Array,
                         axis=-1)
     q = jnp.concatenate([q_nope, q_rope], axis=-1)
     o = attention(q, k, v, q_pos=positions, k_pos=positions, causal=True,
-                  softmax_scale=m.qk_head_dim ** -0.5)
+                  softmax_scale=softmax_scale(cfg))
     return o.reshape(B, S, H * m.v_head_dim) @ p["wo"]
 
 
-def mla_prefill_cache(cfg: ModelConfig, p: Pytree, x: jax.Array,
-                      positions: jax.Array, max_seq: int) -> Pytree:
-    """Latent cache for decode, zero-padded to ``max_seq``."""
-    B, S, _ = x.shape
-    c_kv, k_rope = _latents(cfg, p, x, positions)
-    pad = max_seq - S
-    return {"c_kv": jnp.pad(c_kv, ((0, 0), (0, pad), (0, 0))),
-            "k_rope": jnp.pad(k_rope, ((0, 0), (0, pad), (0, 0)))}
-
-
-def mla_init_cache(cfg: ModelConfig, batch: int, max_seq: int, dtype) -> Pytree:
-    m = cfg.mla
-    return {"c_kv": jnp.zeros((batch, max_seq, m.kv_lora_rank), dtype),
-            "k_rope": jnp.zeros((batch, max_seq, m.qk_rope_head_dim), dtype)}
-
-
-def mla_decode(cfg: ModelConfig, p: Pytree, x: jax.Array, cache: Pytree,
-               pos: jax.Array) -> tuple[jax.Array, Pytree]:
-    """Absorbed-form single-token decode. x: (B, 1, d); pos: (B,)."""
+def mla_decode_pooled(cfg: ModelConfig, p: Pytree, x: jax.Array,
+                      c_pool: jax.Array, r_pool: jax.Array, li: jax.Array,
+                      pos: jax.Array):
+    """Absorbed-form decode of one layer against the pooled latent cache,
+    read where it lies. x: (B, 1, d); pools (L, B, S, kv_lora) and
+    (L, B, S, rope); ``li`` the layer; ``pos`` (B,) each slot's cached
+    length. Each slot attends over its cached positions plus the token's own
+    latent, which is returned, (B, kv_lora) and (B, rope) in the pool's
+    dtype, for the caller to write after its layers.
+    Returns (out (B, 1, d), c_new, kr_new)."""
     m, H = cfg.mla, cfg.n_heads
     B = x.shape[0]
     positions = pos[:, None]
     q_nope, q_rope = _queries(cfg, p, x, positions)      # (B,1,H,·)
     c_new, kr_new = _latents(cfg, p, x, positions)       # (B,1,·)
+    c_new = c_new[:, 0].astype(c_pool.dtype)
+    kr_new = kr_new[:, 0].astype(r_pool.dtype)
+    c_l = jax.lax.dynamic_index_in_dim(c_pool, li, 0, keepdims=False)
+    r_l = jax.lax.dynamic_index_in_dim(r_pool, li, 0, keepdims=False)
 
-    bidx = jnp.arange(B)
-    c_kv = cache["c_kv"].at[bidx, pos].set(c_new[:, 0].astype(cache["c_kv"].dtype))
-    k_rope = cache["k_rope"].at[bidx, pos].set(
-        kr_new[:, 0].astype(cache["k_rope"].dtype))
-
-    # absorb W_uk into the query: q̃_h = q_nope_h @ W_uk_h  -> latent space
-    wkv_b = p["wkv_b"].reshape(m.kv_lora_rank, H, m.qk_nope_head_dim + m.v_head_dim)
+    wkv_b = p["wkv_b"].reshape(m.kv_lora_rank, H,
+                               m.qk_nope_head_dim + m.v_head_dim)
     w_uk = wkv_b[:, :, : m.qk_nope_head_dim]             # (c, H, nope)
     w_uv = wkv_b[:, :, m.qk_nope_head_dim:]              # (c, H, v)
     q_lat = jnp.einsum("bhd,chd->bhc", q_nope[:, 0], w_uk)
-
-    S = c_kv.shape[1]
-    scores = (jnp.einsum("bhc,bsc->bhs", q_lat, c_kv,
-                         preferred_element_type=jnp.float32)
-              + jnp.einsum("bhr,bsr->bhs", q_rope[:, 0], k_rope,
-                           preferred_element_type=jnp.float32))
-    scores = scores * (m.qk_head_dim ** -0.5)
-    valid = jnp.arange(S)[None, :] <= pos[:, None]
-    scores = scores + jnp.where(valid, 0.0, NEG_INF)[:, None, :]
-    probs = jax.nn.softmax(scores, axis=-1)
-    o_lat = jnp.einsum("bhs,bsc->bhc", probs.astype(c_kv.dtype), c_kv)
+    q_r = q_rope[:, 0]
+    scale = softmax_scale(cfg)
+    s = (jnp.einsum("bhc,bsc->bhs", q_lat, c_l,
+                    preferred_element_type=jnp.float32)
+         + jnp.einsum("bhr,bsr->bhs", q_r, r_l,
+                      preferred_element_type=jnp.float32)) * scale
+    s_new = (jnp.einsum("bhc,bc->bh", q_lat, c_new,
+                        preferred_element_type=jnp.float32)
+             + jnp.einsum("bhr,br->bh", q_r, kr_new,
+                          preferred_element_type=jnp.float32)) * scale
+    cached = jnp.arange(c_l.shape[1])[None, :] < pos[:, None]
+    s = jnp.where(cached[:, None, :], s, NEG_INF)
+    mx = jnp.maximum(s.max(-1), s_new)                   # (B, H)
+    e = jnp.exp(s - mx[..., None])
+    e_new = jnp.exp(s_new - mx)
+    denom = e.sum(-1) + e_new
+    o_lat = (jnp.einsum("bhs,bsc->bhc", e.astype(c_l.dtype), c_l,
+                        preferred_element_type=jnp.float32)
+             + e_new[..., None] * c_new[:, None, :].astype(jnp.float32))
+    o_lat = (o_lat / denom[..., None]).astype(x.dtype)
     o = jnp.einsum("bhc,chv->bhv", o_lat, w_uv)          # (B,H,v)
     out = o.reshape(B, 1, H * m.v_head_dim) @ p["wo"]
-    return out, {"c_kv": c_kv, "k_rope": k_rope}
+    return out, c_new, kr_new

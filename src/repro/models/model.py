@@ -21,7 +21,9 @@ Implementation notes
   * decode keeps KV/SSM caches in the scan *carry* and updates slices in place
     (single cache buffer; pairs with buffer donation in the serve step). The
     pooled ``dense``/``localglobal`` step instead reads its (L, B, S, Hkv*hd)
-    pool from outside the scan and writes the step's tokens after it;
+    pool from outside the scan and writes the step's tokens after it, as the
+    MLA ``moe`` step (deepseek-v3) does with its (L, B, S, kv_lora) and
+    (L, B, S, rope) latent pools;
   * architectures with periodic special layers (zamba2 shared attention,
     llama-vision cross-attention) scan over *groups* so special-layer params
     and caches have exact shapes (no dead weights);
@@ -333,13 +335,19 @@ def _dense_decode(cfg: ModelConfig, params: Pytree, state: Pytree,
         step, h, (params["blocks"], jnp.arange(cfg.n_layers), windows))
     h = rms_norm(h, params["final_norm"], cfg.norm_eps)
     logits = _head(cfg, params, h)
-    # one (L, 1, 1, C) update per slot: a scatter over (slot, position)
-    # would have XLA relayout the whole pool around it
-    for b in range(B):
-        at = (0, b, pos[b], 0)
-        ck = jax.lax.dynamic_update_slice(ck, nk[:, b, None, None], at)
-        cv = jax.lax.dynamic_update_slice(cv, nv[:, b, None, None], at)
-    return logits, {"pos": pos + 1, "k": ck, "v": cv}
+    return logits, {"pos": pos + 1, "k": _write_tokens(ck, nk, pos),
+                    "v": _write_tokens(cv, nv, pos)}
+
+
+def _write_tokens(pool: jax.Array, new: jax.Array, pos: jax.Array):
+    """Each slot's new token, ``new`` (L, B, C), written at the slot's
+    position ``pos`` (B,) of the (L, B, S, C) pool: one (L, 1, 1, C) update a
+    slot, in place where the caller donates the pool. A scatter over (slot,
+    position) would have XLA relayout the whole pool around it."""
+    for b in range(pool.shape[1]):
+        pool = jax.lax.dynamic_update_slice(
+            pool, new[:, b, None, None].astype(pool.dtype), (0, b, pos[b], 0))
+    return pool
 
 
 # ======================================================================= moe
@@ -501,7 +509,7 @@ def _moe_prefill(cfg: ModelConfig, params: Pytree, batch: Pytree,
         cache = emit_cache(p, hn)
         h = h + _moe_attn_apply(cfg, p["attn"], hn, positions)
         hn2 = rms_norm(h, p["ln2"], cfg.norm_eps)
-        y, _ = moe_mod.moe_ffn(cfg, p["moe"], hn2)
+        y, _ = moe_mod.moe_ffn_serve(cfg, p["moe"], hn2)
         if cfg.dense_residual:
             y = y + mlp_block(p["dense_mlp"], hn2)
         return h + y, cache
@@ -512,6 +520,8 @@ def _moe_prefill(cfg: ModelConfig, params: Pytree, batch: Pytree,
         state["dense_cache"] = dc
     h, mc = jax.lax.scan(moe_step, h, params["moe_blocks"])
     state["moe_cache"] = mc
+    if cfg.mla is not None:
+        state["routed"] = _no_routing(cfg, B)
     h = rms_norm(h, params["final_norm"], cfg.norm_eps)
     return _head(cfg, params, h[:, -1:]), state
 
@@ -533,20 +543,42 @@ def _moe_decode_state(cfg: ModelConfig, batch: int, max_seq: int) -> Pytree:
     if cfg.first_dense_layers:
         state["dense_cache"] = cache(cfg.first_dense_layers)
     state["moe_cache"] = cache(n_moe)
+    if cfg.mla is not None:
+        state["routed"] = _no_routing(cfg, batch)
     return state
 
 
-def _moe_attn_decode(cfg: ModelConfig, p: Pytree, h, cache_pair, li, pos):
-    """One-layer attention decode; returns (attn_out, updated (c1_l, c2_l))."""
+def _no_routing(cfg: ModelConfig, batch: int) -> jax.Array:
+    """The ``routed`` leaf of an MLA decode state before any step: (n_moe,
+    B, k) expert ids, -1 for none."""
+    n_moe = cfg.n_layers - cfg.first_dense_layers
+    return jnp.full((n_moe, batch, cfg.experts_per_token), -1, jnp.int32)
+
+
+def routing_counts(cfg: ModelConfig, state: Pytree, slots
+                   ) -> tuple[int, int] | None:
+    """From the last step's ``routed`` leaf, for the slots ``slots``: the
+    held experts that got at least one of their tokens, summed over MoE
+    layers, and their token picks that landed on held experts. None for a
+    state without routing."""
+    if "routed" not in state:
+        return None
+    ids = np.asarray(state["routed"])[:, list(slots)]        # (n_moe, b, k)
+    first, n = cfg.experts_held
+    held = (ids >= first) & (ids < first + n)
+    touched = sum(len(np.unique(layer[h])) for layer, h in zip(ids, held))
+    return touched, int(held.sum())
+
+
+def _gqa_moe_attn_decode(cfg: ModelConfig, p: Pytree, h, cache_pair, li,
+                         pos):
+    """One-layer GQA attention decode (arctic); returns (attn_out, updated
+    (k_l, v_l))."""
     B = h.shape[0]
     bidx = jnp.arange(B)
     c1, c2 = cache_pair
     c1_l = jax.lax.dynamic_index_in_dim(c1, li, 0, keepdims=False)
     c2_l = jax.lax.dynamic_index_in_dim(c2, li, 0, keepdims=False)
-    if cfg.mla is not None:
-        out, new = mla_mod.mla_decode(cfg, p, h, {"c_kv": c1_l, "k_rope": c2_l},
-                                      pos)
-        return out, (new["c_kv"], new["k_rope"])
     dims = _dims(cfg)
     q = (h @ p["wq"]).reshape(B, 1, dims.n_heads, dims.hd)
     k = (h @ p["wk"]).reshape(B, 1, dims.n_kv_heads, dims.hd)
@@ -561,44 +593,75 @@ def _moe_attn_decode(cfg: ModelConfig, p: Pytree, h, cache_pair, li, pos):
 
 def _moe_decode(cfg: ModelConfig, params: Pytree, state: Pytree,
                 tokens: jax.Array):
-    B = tokens.shape[0]
+    if cfg.mla is not None:
+        return _mla_decode(cfg, params, state, tokens)
     pos = state["pos"]
     h = _embed(params, tokens)
     new_state = {"pos": pos + 1}
 
-    def mk_step(moe: bool):
-        def step(carry, x):
-            h, c1, c2 = carry
-            p, li = x
-            hn = rms_norm(h, p["ln1"], cfg.norm_eps)
-            o, (c1_l, c2_l) = _moe_attn_decode(cfg, p["attn"], hn, (c1, c2),
+    def step(carry, x):
+        h, c1, c2 = carry
+        p, li = x
+        hn = rms_norm(h, p["ln1"], cfg.norm_eps)
+        o, (c1_l, c2_l) = _gqa_moe_attn_decode(cfg, p["attn"], hn, (c1, c2),
                                                li, pos)
-            h = h + o
-            hn2 = rms_norm(h, p["ln2"], cfg.norm_eps)
-            if moe:
-                y, _ = moe_mod.moe_ffn(cfg, p["moe"], hn2)
-                if cfg.dense_residual:
-                    y = y + mlp_block(p["dense_mlp"], hn2)
-            else:
-                y = mlp_block(p["mlp"], hn2)
-            h = h + y
-            c1 = jax.lax.dynamic_update_index_in_dim(c1, c1_l, li, 0)
-            c2 = jax.lax.dynamic_update_index_in_dim(c2, c2_l, li, 0)
-            return (h, c1, c2), None
-        return step
+        h = h + o
+        hn2 = rms_norm(h, p["ln2"], cfg.norm_eps)
+        y, _ = moe_mod.moe_ffn_serve(cfg, p["moe"], hn2)
+        if cfg.dense_residual:
+            y = y + mlp_block(p["dense_mlp"], hn2)
+        h = h + y
+        c1 = jax.lax.dynamic_update_index_in_dim(c1, c1_l, li, 0)
+        c2 = jax.lax.dynamic_update_index_in_dim(c2, c2_l, li, 0)
+        return (h, c1, c2), None
 
-    if cfg.first_dense_layers:
-        c1, c2 = state["dense_cache"]
-        (h, c1, c2), _ = jax.lax.scan(
-            mk_step(False), (h, c1, c2),
-            (params["dense_blocks"], jnp.arange(cfg.first_dense_layers)))
-        new_state["dense_cache"] = (c1, c2)
     c1, c2 = state["moe_cache"]
     n_moe = cfg.n_layers - cfg.first_dense_layers
     (h, c1, c2), _ = jax.lax.scan(
-        mk_step(True), (h, c1, c2),
-        (params["moe_blocks"], jnp.arange(n_moe)))
+        step, (h, c1, c2), (params["moe_blocks"], jnp.arange(n_moe)))
     new_state["moe_cache"] = (c1, c2)
+    h = rms_norm(h, params["final_norm"], cfg.norm_eps)
+    return _head(cfg, params, h), new_state
+
+
+def _mla_decode(cfg: ModelConfig, params: Pytree, state: Pytree,
+                tokens: jax.Array):
+    """One token for every slot over the latent pools. Each scan reads its
+    pools and never writes them: a layer attends over the slot's cached
+    latents plus the token's own, and emits the token's latents; after the
+    scans, one update a slot writes them into the pools (in place where the
+    caller donates the state). A slot whose token is negative holds no
+    session: its cached length is set to 0 first. ``routed`` keeps each
+    slot's experts at each MoE layer."""
+    pos = jnp.where(tokens[:, 0] >= 0, state["pos"], 0)      # (B,)
+    h = _embed(params, jnp.maximum(tokens, 0))               # (B,1,d)
+    new_state = {"pos": pos + 1}
+
+    def layers(h, blocks, pools, moe: bool):
+        c_pool, r_pool = pools
+
+        def step(h, x):
+            p, li = x
+            o, c_new, kr_new = mla_mod.mla_decode_pooled(
+                cfg, p["attn"], rms_norm(h, p["ln1"], cfg.norm_eps), c_pool,
+                r_pool, li, pos)
+            h = h + o
+            hn = rms_norm(h, p["ln2"], cfg.norm_eps)
+            if not moe:
+                return h + mlp_block(p["mlp"], hn), (c_new, kr_new, ())
+            y, ids = moe_mod.moe_ffn_serve(cfg, p["moe"], hn)
+            return h + y, (c_new, kr_new, ids[:, 0])
+
+        n = jax.tree.leaves(blocks)[0].shape[0]
+        h, (nc, nr, ids) = jax.lax.scan(step, h, (blocks, jnp.arange(n)))
+        return h, (_write_tokens(c_pool, nc, pos),
+                   _write_tokens(r_pool, nr, pos)), ids
+
+    if cfg.first_dense_layers:
+        h, new_state["dense_cache"], _ = layers(
+            h, params["dense_blocks"], state["dense_cache"], False)
+    h, new_state["moe_cache"], new_state["routed"] = layers(
+        h, params["moe_blocks"], state["moe_cache"], True)
     h = rms_norm(h, params["final_norm"], cfg.norm_eps)
     return _head(cfg, params, h), new_state
 
@@ -1313,9 +1376,9 @@ def decode_step(cfg: ModelConfig, params: Pytree, state: Pytree,
                 tokens: jax.Array):
     """One token for every slot of ``state``; ``tokens`` (B, 1). A negative
     token marks a slot that holds no session, whose output is to be
-    discarded; the pooled families (``dense``, ``localglobal``) read none of
-    its cache."""
-    if cfg.family not in _POOLED_FAMILIES:
+    discarded; the pooled families (``dense``, ``localglobal``) and the MLA
+    ``moe`` step read none of its cache."""
+    if cfg.family not in _POOLED_FAMILIES and cfg.mla is None:
         tokens = jnp.maximum(tokens, 0)
     return _FAMILY[cfg.family][4](cfg, params, state, tokens)
 

@@ -1,15 +1,24 @@
-"""Mixture-of-Experts layer — capacity-based token-choice dispatch.
+"""Mixture-of-Experts layer: the router, and two dispatches.
 
 Covers both assigned MoE architectures:
-  * deepseek-v3-671b: 1 shared expert + 256 routed, top-8, sigmoid-ish router
-    (we use softmax + renormalized top-k weights), first 3 layers dense.
-  * arctic-480b: 128 routed top-2 + a *dense residual* FFN in parallel.
+  * deepseek-v3-671b: 1 shared expert + 256 routed, top-8, with the published
+    router (``router_score="sigmoid"``, :func:`route`), first 3 layers dense.
+  * arctic-480b: 128 routed top-2 (softmax, renormalised) + a *dense
+    residual* FFN in parallel.
 
-Dispatch is the GShard/Switch capacity scheme — top-k per token, position
-within expert via per-slot cumsum, scatter to (E, C, d), expert einsum, gather
-back. This is dense-shape, compiles under pjit, and shards cleanly with
-experts on the "model" axis (EP) and tokens on "data" — the all-to-all shows
-up explicitly in the dry-run collective accounting.
+Serving (:func:`moe_ffn_serve`, prefill and decode) drops no token. The
+layer holds the routed experts ``cfg.experts_held`` of the ``n_experts`` it
+routes over (one chip's share under expert parallelism; all of them by
+default): each held expert runs on every token, and a token's result is
+weighted by its routing weight for that expert, zero where it did not choose
+it. What experts held elsewhere would add is left out.
+
+Training (:func:`moe_ffn`) uses the GShard/Switch capacity scheme — top-k
+per token, position within expert via a sort, scatter to (E, C, d), expert
+einsum, gather back. This is dense-shape, compiles under pjit, and shards
+cleanly with experts on the "model" axis (EP) and tokens on "data" — the
+all-to-all shows up explicitly in the dry-run collective accounting. Tokens
+beyond an expert's capacity are dropped there.
 """
 
 from __future__ import annotations
@@ -27,11 +36,13 @@ Pytree = Any
 
 
 def init_moe(key: jax.Array, cfg: ModelConfig, dtype, n_layers: int = 1) -> Pytree:
-    d, E, ff = cfg.d_model, cfg.n_experts, cfg.moe_d_ff
+    d, ff = cfg.d_model, cfg.moe_d_ff
+    E = cfg.experts_held[1]
     ks = jax.random.split(key, 5)
     out_scale = 1.0 / np.sqrt(ff) / np.sqrt(2.0 * n_layers)
     p = {
-        "router": init_linear(ks[0], d, E, jnp.float32),  # router in f32
+        # the router scores every routed expert, held here or not (f32)
+        "router": init_linear(ks[0], d, cfg.n_experts, jnp.float32),
         "w1": (0.02 * jax.random.normal(ks[1], (E, d, ff), jnp.float32)
                ).astype(dtype),
         "w3": (0.02 * jax.random.normal(ks[2], (E, d, ff), jnp.float32)
@@ -39,6 +50,10 @@ def init_moe(key: jax.Array, cfg: ModelConfig, dtype, n_layers: int = 1) -> Pytr
         "w2": (out_scale * jax.random.normal(ks[3], (E, ff, d), jnp.float32)
                ).astype(dtype),
     }
+    if cfg.router_score == "sigmoid":
+        # selection bias per routed expert (DeepSeek-V3's
+        # e_score_correction_bias); it never enters the weights
+        p["router_bias"] = jnp.zeros((cfg.n_experts,), jnp.float32)
     if cfg.n_shared_experts:
         ff_s = ff * cfg.n_shared_experts
         kk = jax.random.split(ks[4], 3)
@@ -56,9 +71,75 @@ def _capacity(cfg: ModelConfig, n_tokens: int) -> int:
     return max(8, int(np.ceil(c / 8)) * 8)
 
 
+def route(cfg: ModelConfig, router: jax.Array, bias: jax.Array | None,
+          xt: jax.Array) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """Each token's experts and their weights. xt: (T, d) ->
+    (weights (T, k) f32, expert ids (T, k) over all ``n_experts``, per-expert
+    probabilities (T, E) f32 for the load-balancing loss).
+
+    ``softmax``: the top k of the softmax, renormalised. ``sigmoid``
+    (DeepSeek-V3, ``noaux_tc``): scores are sigmoids; experts are chosen by
+    score + ``bias``, only within the ``topk_groups`` of ``n_groups`` groups
+    whose two best biased scores sum highest; the weights are the chosen
+    experts' scores without the bias, normalised to sum 1, times
+    ``routed_scale``."""
+    k = cfg.experts_per_token
+    logits = xt.astype(jnp.float32) @ router.astype(jnp.float32)   # (T, E)
+    if cfg.router_score == "softmax":
+        probs = jax.nn.softmax(logits, axis=-1)
+        topw, topi = jax.lax.top_k(probs, k)
+        topw = topw / jnp.maximum(topw.sum(-1, keepdims=True), 1e-9)
+        return topw * cfg.routed_scale, topi, probs
+    scores = jax.nn.sigmoid(logits)
+    choice = scores if bias is None else scores + bias.astype(jnp.float32)
+    if cfg.n_groups > 1:
+        T, E = choice.shape
+        g = choice.reshape(T, cfg.n_groups, E // cfg.n_groups)
+        group_score = jax.lax.top_k(g, 2)[0].sum(-1)                # (T, G)
+        _, gi = jax.lax.top_k(group_score, cfg.topk_groups)
+        keep = jax.nn.one_hot(gi, cfg.n_groups, dtype=jnp.bool_).any(1)
+        choice = jnp.where(keep[:, :, None], g, -jnp.inf).reshape(T, E)
+    _, topi = jax.lax.top_k(choice, k)
+    topw = jnp.take_along_axis(scores, topi, axis=-1)
+    topw = topw / jnp.maximum(topw.sum(-1, keepdims=True), 1e-20)
+    probs = scores / scores.sum(-1, keepdims=True)
+    return topw * cfg.routed_scale, topi, probs
+
+
+def _shared(p: Pytree, xt: jax.Array) -> jax.Array:
+    sp = p["shared"]
+    return (jax.nn.silu(xt @ sp["w1"]) * (xt @ sp["w3"])) @ sp["w2"]
+
+
+def moe_ffn_serve(cfg: ModelConfig, p: Pytree, x: jax.Array
+                  ) -> tuple[jax.Array, jax.Array]:
+    """The serving dispatch, which drops no token. x: (B, S, d) ->
+    (out (B, S, d), each token's chosen expert ids (B, S, k) over all
+    ``n_experts``).
+
+    Every held expert runs on every token; a token's result from it is
+    weighted by the token's routing weight for it, zero where the token did
+    not choose it. The shared expert is added unweighted."""
+    B, S, d = x.shape
+    xt = x.reshape(B * S, d)
+    topw, topi, _ = route(cfg, p["router"], p.get("router_bias"), xt)
+    first, n = cfg.experts_held
+    chose = topi[:, :, None] == first + jnp.arange(n)             # (T, k, n)
+    comb = jnp.sum(jnp.where(chose, topw[:, :, None], 0.0), axis=1)  # (T, n)
+    h = jnp.einsum("td,edf->etf", xt, p["w1"])
+    g = jnp.einsum("td,edf->etf", xt, p["w3"])
+    y = jnp.einsum("etf,efd->etd", jax.nn.silu(h) * g, p["w2"])
+    out = jnp.einsum("te,etd->td", comb.astype(y.dtype), y,
+                     preferred_element_type=jnp.float32).astype(x.dtype)
+    if "shared" in p:
+        out = out + _shared(p, xt)
+    return out.reshape(B, S, d), topi.reshape(B, S, -1)
+
+
 def moe_ffn(cfg: ModelConfig, p: Pytree, x: jax.Array
             ) -> tuple[jax.Array, jax.Array]:
-    """x: (B, S, d) -> (out (B, S, d), router aux loss scalar f32).
+    """The training dispatch. x: (B, S, d) -> (out (B, S, d), router aux loss
+    scalar f32). The layer must hold every routed expert.
 
     Under an active mesh (dist.hints.sharding_rules) the routed experts run
     through the shard_map path (local dispatch + psum combine — see
@@ -66,6 +147,9 @@ def moe_ffn(cfg: ModelConfig, p: Pytree, x: jax.Array
     used on unmeshed CPU runs and as the numerical oracle in tests.
     """
     from repro.dist import hints as hint_rules
+    if cfg.ep_size != 1:
+        raise ValueError("the training dispatch needs every routed expert "
+                         f"held; {cfg.name} holds {cfg.experts_held}")
     r = hint_rules.get_rules()
     if r is not None and r.get("mesh") is not None:
         return _moe_ffn_sharded(cfg, p, x, r)
@@ -80,10 +164,7 @@ def _moe_ffn_global(cfg: ModelConfig, p: Pytree, x: jax.Array
     C = _capacity(cfg, T)
     xt = x.reshape(T, d)
 
-    logits = (xt.astype(jnp.float32) @ p["router"])            # (T, E) f32
-    probs = jax.nn.softmax(logits, axis=-1)
-    topw, topi = jax.lax.top_k(probs, k)                       # (T, k)
-    topw = topw / jnp.maximum(topw.sum(-1, keepdims=True), 1e-9)
+    topw, topi, probs = route(cfg, p["router"], p.get("router_bias"), xt)
 
     # Position of each (token, slot) inside its expert, via a single stable
     # sort over the T*k flat assignments. (The obvious per-slot one-hot
@@ -129,8 +210,7 @@ def _moe_ffn_global(cfg: ModelConfig, p: Pytree, x: jax.Array
     aux = E * jnp.sum(frac * mean_prob) * cfg.router_aux_weight
 
     if "shared" in p:
-        sp = p["shared"]
-        out = out + ((jax.nn.silu(xt @ sp["w1"]) * (xt @ sp["w3"])) @ sp["w2"])
+        out = out + _shared(p, xt)
     return out.reshape(B, S, d), aux
 
 
@@ -173,20 +253,18 @@ def _moe_ffn_sharded(cfg: ModelConfig, p: Pytree, x: jax.Array, rules: dict
     dp_spec = dp_axes if len(dp_axes) > 1 else (dp_axes[0] if dp_axes else None)
     in_specs = (P(dp_spec, None, None),          # x: tokens over dp
                 P(None, None),                   # router (replicated inside)
+                P(None),                         # router bias
                 P(tp, None, "data"),             # w1 (E, d, ff)
                 P(tp, None, "data"),             # w3
                 P(tp, "data", None))             # w2 (E, ff, d)
     out_specs = (P(dp_spec, None, None), P())
 
-    def local_fn(x_loc, router, w1, w3, w2):
+    def local_fn(x_loc, router, bias, w1, w3, w2):
         Bl, Sl, _ = x_loc.shape
         xt = x_loc.reshape(Bl * Sl, d)
         Tl = Bl * Sl
 
-        logits = xt.astype(jnp.float32) @ router           # (Tl, E)
-        probs = jax.nn.softmax(logits, axis=-1)
-        topw, topi = jax.lax.top_k(probs, k)
-        topw = topw / jnp.maximum(topw.sum(-1, keepdims=True), 1e-9)
+        topw, topi, probs = route(cfg, router, bias, xt)
 
         e_flat = topi.reshape(-1)                          # (Tl*k,)
         order = jnp.argsort(e_flat, stable=True)
@@ -233,11 +311,10 @@ def _moe_ffn_sharded(cfg: ModelConfig, p: Pytree, x: jax.Array, rules: dict
 
     out, aux = jax.shard_map(local_fn, mesh=mesh, in_specs=in_specs,
                              out_specs=out_specs, check_vma=False)(
-        x, p["router"].astype(jnp.float32), p["w1"], p["w3"], p["w2"])
+        x, p["router"].astype(jnp.float32),
+        p.get("router_bias", jnp.zeros((E,), jnp.float32)), p["w1"], p["w3"],
+        p["w2"])
 
     if "shared" in p:
-        sp = p["shared"]
-        xt = x.reshape(B * S, d)
-        out = out + ((jax.nn.silu(xt @ sp["w1"]) * (xt @ sp["w3"]))
-                     @ sp["w2"]).reshape(B, S, d)
+        out = out + _shared(p, x.reshape(B * S, d)).reshape(B, S, d)
     return out, aux

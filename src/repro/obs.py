@@ -11,7 +11,8 @@ in memory. With no profiler collecting, a span costs one check and is a
 shared null context.
 
 ``count(name, n)`` adds ``n`` to a named counter (``engine.kv_blocks_read``,
-...). Like a span, it records only while a profiler collects.
+...). Like a span, it records only while a profiler collects, which
+``collecting()`` tells a caller whose count costs work of its own.
 
 :func:`summary` reduces the buffer per span name and gives the counters;
 :func:`reset` empties both. The profile is the only exporter of spans.
@@ -32,8 +33,8 @@ from typing import Any, Callable, NamedTuple
 
 import jax
 
-__all__ = ["Record", "span", "traced", "count", "summary", "reset",
-           "records", "compiles"]
+__all__ = ["Record", "span", "traced", "count", "collecting", "summary",
+           "reset", "records", "compiles"]
 
 PREFIX = "repro."
 MAX_RECORDS = 1 << 16
@@ -161,6 +162,12 @@ def traced(name: str) -> Callable:
         return inner
 
     return wrap
+
+
+def collecting() -> bool:
+    """True while a profiler collects, that is while spans and counters
+    record: a caller checks it before it computes what it would count."""
+    return _collecting()
 
 
 def count(name: str, n: int = 1) -> None:

@@ -526,11 +526,22 @@ class ServingEngine:
             if blocks is not None:
                 obs.count("engine.kv_blocks_read", blocks[0])
                 obs.count("engine.kv_blocks_pool", blocks[1])
+            obs.count("engine.decode_steps")
+            obs.count("engine.decode_tokens", len(live))
+            obs.count("engine.kv_tokens_live", sum(lengths))
             # the state handed to decode is donated: only the returned one
             # may be read
             arg, self.state = self.backend.decode(self.params, self.state,
                                                   tokens)
             self.steps += 1
+            if obs.collecting() and self.cfg is not None:
+                # the step's routing, read after its tokens: no wait of its
+                # own, and none at all in an untraced run
+                routed = M.routing_counts(self.cfg, self.state,
+                                          [s.slot for s in live])
+                if routed is not None:
+                    obs.count("moe.experts_touched", routed[0])
+                    obs.count("moe.tokens_held", routed[1])
             out: dict[int, int] = {}
             for s in live:
                 tok = int(arg[s.slot])

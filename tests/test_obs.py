@@ -104,7 +104,9 @@ def test_counters_add_while_a_profiler_collects_and_reset(tmp_path):
 def test_engine_counts_the_kv_blocks_a_step_reads(model, tmp_path):
     """At max_seq 1024 a block is 512 positions: in each layer a slot of
     cached length 3 reads one block, one of 600 reads two, an idle slot one
-    (its first index is fetched); the pool holds 3 x 2 blocks a layer."""
+    (its first index is fetched); the pool holds 3 x 2 blocks a layer. Each
+    step also counts itself, its live sequences and their cached
+    positions."""
     cfg, params = model
     eng = ServingEngine(cfg, params, max_batch=3, max_seq=1024)
     eng.submit([1, 2, 3])
@@ -114,7 +116,10 @@ def test_engine_counts_the_kv_blocks_a_step_reads(model, tmp_path):
         eng.step()                      # 4, 601
     L = cfg.n_layers
     assert obs.summary()["counters"] == {"engine.kv_blocks_read": 2 * 4 * L,
-                                         "engine.kv_blocks_pool": 2 * 6 * L}
+                                         "engine.kv_blocks_pool": 2 * 6 * L,
+                                         "engine.decode_steps": 2,
+                                         "engine.decode_tokens": 4,
+                                         "engine.kv_tokens_live": 603 + 605}
 
 
 def test_nested_spans_carry_parents_self_time_and_keys(tmp_path):

@@ -131,6 +131,42 @@ def test_granite_decode_step_fits_one_v5e(one_chip, no_persistent_cache,
     assert not re.search(r"= bf16\[40,8,4096,512\]\S* copy\(", txt)
 
 
+def test_deepseek_decode_step_fits_one_v5e(one_chip, no_persistent_cache):
+    """The benchmark's deepseek-v3-671b share (1 dense + 8 MoE layers at
+    published widths, 32 heads and 8 of 256 experts held, 32 x 8192 latent
+    pool) decodes on one chip: the latent pools are read where they lie and
+    written in place, aliased to the donated state, and no op copies a
+    whole pool."""
+    import json
+    import sys
+    from pathlib import Path
+    root = Path(__file__).resolve().parents[1]
+    sys.path.insert(0, str(root))
+    from bench import harness
+    path = root / "bench" / "configs" / "deepseek-v3-671b.json"
+    bcfg = json.loads(path.read_text())
+    cfg = harness.load_module(path.with_suffix(".py")).model_config(bcfg)
+    B, S = bcfg["serving"]["max_batch"], bcfg["serving"]["max_seq"]
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: _shape(one_chip, x.shape, x.dtype), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda: M.init_params(cfg, jax.random.PRNGKey(0))))
+    state = on_chip(jax.eval_shape(lambda: M.init_decode_state(cfg, B, S)))
+    compiled = jax.jit(lambda p, s, t: M.decode_step(cfg, p, s, t),
+                       donate_argnums=(1,)).lower(
+        params, state, _shape(one_chip, (B, 1), jnp.int32)).compile()
+    ma = compiled.memory_analysis()
+    pools = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(
+        (state["dense_cache"], state["moe_cache"])))          # 2.72 GB
+    assert ma.alias_size_in_bytes >= pools
+    assert ma.temp_size_in_bytes < pools // 50
+    assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < V5E_HBM_BYTES
+    assert not re.search(r"= bf16\[\d+,32,8192,(512|64)\]\S* copy\(",
+                         compiled.as_text())
+
+
 def test_granite_decode_step_shards_over_v5e_2x2(topo, no_persistent_cache,
                                                 monkeypatch):
     """The pooled decode step with the Pallas kernel on a 2x2 (data, model)
