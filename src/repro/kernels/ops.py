@@ -7,9 +7,11 @@
     what the dry-run lowers, since Pallas TPU kernels cannot compile for the
     host-CPU placeholder devices).
 
-Decode attention has no such wrapper: the pooled decode step
-(``models.model``) calls ``decode_attention.decode_attention`` on a TPU and
-``layers.decode_attention`` elsewhere.
+Decode attention has no such wrapper: on a TPU the pooled decode step
+(``models.model``) calls ``decode_attention.decode_attention`` for GQA
+(``dense``, ``localglobal``) and ``mla_decode.mla_decode_attention`` for
+MLA's latent pool (deepseek-v3); elsewhere ``layers.decode_attention`` and
+the XLA einsums of ``mla.mla_decode_pooled`` attend.
 """
 
 from __future__ import annotations

@@ -92,13 +92,16 @@ def mla_attention(cfg: ModelConfig, p: Pytree, x: jax.Array,
 
 def mla_decode_pooled(cfg: ModelConfig, p: Pytree, x: jax.Array,
                       c_pool: jax.Array, r_pool: jax.Array, li: jax.Array,
-                      pos: jax.Array):
+                      pos: jax.Array, kernel=None):
     """Absorbed-form decode of one layer against the pooled latent cache,
     read where it lies. x: (B, 1, d); pools (L, B, S, kv_lora) and
     (L, B, S, rope); ``li`` the layer; ``pos`` (B,) each slot's cached
     length. Each slot attends over its cached positions plus the token's own
     latent, which is returned, (B, kv_lora) and (B, rope) in the pool's
-    dtype, for the caller to write after its layers.
+    dtype, for the caller to write after its layers. ``kernel``: the Pallas
+    kernel ``kernels.mla_decode.mla_decode_attention`` with its options
+    bound, which reads each slot's live latent blocks once; None for XLA
+    einsums over every position of the layer's pools.
     Returns (out (B, 1, d), c_new, kr_new)."""
     m, H = cfg.mla, cfg.n_heads
     B = x.shape[0]
@@ -107,8 +110,6 @@ def mla_decode_pooled(cfg: ModelConfig, p: Pytree, x: jax.Array,
     c_new, kr_new = _latents(cfg, p, x, positions)       # (B,1,·)
     c_new = c_new[:, 0].astype(c_pool.dtype)
     kr_new = kr_new[:, 0].astype(r_pool.dtype)
-    c_l = jax.lax.dynamic_index_in_dim(c_pool, li, 0, keepdims=False)
-    r_l = jax.lax.dynamic_index_in_dim(r_pool, li, 0, keepdims=False)
 
     wkv_b = p["wkv_b"].reshape(m.kv_lora_rank, H,
                                m.qk_nope_head_dim + m.v_head_dim)
@@ -116,7 +117,23 @@ def mla_decode_pooled(cfg: ModelConfig, p: Pytree, x: jax.Array,
     w_uv = wkv_b[:, :, m.qk_nope_head_dim:]              # (c, H, v)
     q_lat = jnp.einsum("bhd,chd->bhc", q_nope[:, 0], w_uk)
     q_r = q_rope[:, 0]
-    scale = softmax_scale(cfg)
+    if kernel is None:
+        o_lat = _absorbed_xla(q_lat, q_r, c_pool, r_pool, c_new, kr_new, pos,
+                              li, softmax_scale(cfg))
+    else:
+        o_lat = kernel(q_lat, q_r, c_pool, r_pool, c_new, kr_new, pos, li)
+    o = jnp.einsum("bhc,chv->bhv", o_lat.astype(x.dtype), w_uv)   # (B,H,v)
+    out = o.reshape(B, 1, H * m.v_head_dim) @ p["wo"]
+    return out, c_new, kr_new
+
+
+def _absorbed_xla(q_lat, q_r, c_pool, r_pool, c_new, kr_new, pos, li,
+                  scale: float) -> jax.Array:
+    """What ``mla_decode_attention`` computes, as XLA einsums over every
+    position of layer ``li``'s pools, the uncached ones masked. Returns
+    o_lat (B, H, kv_lora) in f32."""
+    c_l = jax.lax.dynamic_index_in_dim(c_pool, li, 0, keepdims=False)
+    r_l = jax.lax.dynamic_index_in_dim(r_pool, li, 0, keepdims=False)
     s = (jnp.einsum("bhc,bsc->bhs", q_lat, c_l,
                     preferred_element_type=jnp.float32)
          + jnp.einsum("bhr,bsr->bhs", q_r, r_l,
@@ -134,7 +151,4 @@ def mla_decode_pooled(cfg: ModelConfig, p: Pytree, x: jax.Array,
     o_lat = (jnp.einsum("bhs,bsc->bhc", e.astype(c_l.dtype), c_l,
                         preferred_element_type=jnp.float32)
              + e_new[..., None] * c_new[:, None, :].astype(jnp.float32))
-    o_lat = (o_lat / denom[..., None]).astype(x.dtype)
-    o = jnp.einsum("bhc,chv->bhv", o_lat, w_uv)          # (B,H,v)
-    out = o.reshape(B, 1, H * m.v_head_dim) @ p["wo"]
-    return out, c_new, kr_new
+    return o_lat / denom[..., None]

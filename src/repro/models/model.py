@@ -240,8 +240,16 @@ def pooled_kv_blocks(cfg: ModelConfig, lengths, max_seq: int
                      ) -> tuple[int, int] | None:
     """Blocks of K (as many again of V) that one pooled decode step fetches
     over its layers, each with its window, for these per-slot cached
-    lengths; and the blocks the pool holds over its layers. None for a
-    family whose decode state is not pooled."""
+    lengths; and the blocks the pool holds over its layers. For MLA, the
+    blocks of the latent pool (its rope pool alongside) over the dense and
+    MoE layers. None for a family whose decode state is not pooled."""
+    if cfg.mla is not None:
+        from repro.kernels import mla_decode
+        from repro.kernels.decode_attention import block_size, blocks_fetched
+        bk = mla_decode.DEFAULT_BK
+        per_slot = max_seq // block_size(max_seq, bk)
+        return (cfg.n_layers * blocks_fetched(lengths, max_seq, bk),
+                cfg.n_layers * len(lengths) * per_slot)
     if cfg.family not in _POOLED_FAMILIES:
         return None
     from repro.kernels import decode_attention as kernel
@@ -253,8 +261,8 @@ def pooled_kv_blocks(cfg: ModelConfig, lengths, max_seq: int
 
 
 def _pooled_attention_impl() -> str:
-    """How the pooled decode step attends: the Pallas kernel ("pallas") on a
-    TPU backend, ``layers.decode_attention`` ("xla") elsewhere."""
+    """How the pooled decode steps attend: the Pallas kernels ("pallas") on
+    a TPU backend, XLA ("xla") elsewhere; GQA's and MLA's alike."""
     return "pallas" if jax.default_backend() == "tpu" else "xla"
 
 
@@ -286,6 +294,22 @@ def _pooled_kernel(cfg: ModelConfig, pool: jax.Array):
         kernel, mesh=rules["mesh"],
         in_specs=(P(b, c, None), kv, kv, P(b, c), P(b, c), P(b), P(), P()),
         out_specs=P(b, c, None), check_vma=False)
+
+
+def _latent_kernel(cfg: ModelConfig):
+    """The MLA decode kernel, with its options bound, or None where the XLA
+    einsums of ``mla.mla_decode_pooled`` attend: off a TPU, and under a mesh
+    (``dist.hints.sharding_rules``), whose rules put the latent pool's
+    positions on "model" (the latent has no heads to take it), so that the
+    softmax would have to merge across chips."""
+    from repro.dist.hints import get_rules
+    impl = _pooled_attention_impl()
+    if impl == "xla" or get_rules() is not None:
+        return None
+    from repro.kernels.mla_decode import mla_decode_attention
+    return partial(mla_decode_attention,
+                   softmax_scale=mla_mod.softmax_scale(cfg),
+                   interpret=impl == "interpret")
 
 
 def _dense_decode(cfg: ModelConfig, params: Pytree, state: Pytree,
@@ -636,6 +660,7 @@ def _mla_decode(cfg: ModelConfig, params: Pytree, state: Pytree,
     pos = jnp.where(tokens[:, 0] >= 0, state["pos"], 0)      # (B,)
     h = _embed(params, jnp.maximum(tokens, 0))               # (B,1,d)
     new_state = {"pos": pos + 1}
+    kernel = _latent_kernel(cfg)
 
     def layers(h, blocks, pools, moe: bool):
         c_pool, r_pool = pools
@@ -644,7 +669,7 @@ def _mla_decode(cfg: ModelConfig, params: Pytree, state: Pytree,
             p, li = x
             o, c_new, kr_new = mla_mod.mla_decode_pooled(
                 cfg, p["attn"], rms_norm(h, p["ln1"], cfg.norm_eps), c_pool,
-                r_pool, li, pos)
+                r_pool, li, pos, kernel)
             h = h + o
             hn = rms_norm(h, p["ln2"], cfg.norm_eps)
             if not moe:

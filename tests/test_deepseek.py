@@ -93,9 +93,15 @@ def gap(cfg: dict, seed: int, mcfg=None) -> float:
     return float(np.abs(got - want).max())
 
 
-@pytest.mark.parametrize("held,rank", [(2, 1), (16, 0)],
-                         ids=["share-of-8-ranks", "all-held"])
-def test_prefill_then_pooled_decode_matches_the_reference(held, rank):
+@pytest.mark.parametrize("held,rank,impl", [
+    (2, 1, "xla"), (16, 0, "xla"), (2, 1, "interpret"), (16, 0, "interpret")],
+    ids=["share-of-8-ranks", "all-held", "share-of-8-ranks-kernel",
+         "all-held-kernel"])
+def test_prefill_then_pooled_decode_matches_the_reference(held, rank, impl,
+                                                          monkeypatch):
+    """Through the XLA einsums and through the MLA decode kernel (in
+    interpret mode, as a TPU would run it)."""
+    monkeypatch.setattr(M, "_pooled_attention_impl", lambda: impl)
     cfg = small_cfg(held, rank)
     got, seq = served_logits(cfg, 11)
     want = reference_logits(cfg, 11, seq)
@@ -223,6 +229,51 @@ def test_engine_counts_the_experts_a_step_touches(tmp_path):
     assert c["moe.tokens_held"] > 0
     assert eng.state["routed"].shape == (2, 3, 4)
     eng.finish(a)
+
+
+def test_engine_counts_the_latent_blocks_a_step_reads(tmp_path):
+    """Each step counts the latent blocks the MLA kernel fetches over the
+    dense and MoE layers, for the slots' cached lengths (an idle slot
+    fetches one), and the blocks the pools hold."""
+    from repro.kernels.decode_attention import block_size, blocks_fetched
+    from repro.kernels.mla_decode import DEFAULT_BK
+    cfg = small_cfg(2, 1)
+    mcfg = ref_mod.model_config(cfg)
+    eng = ServingEngine(mcfg, ref_mod.make_weights(cfg, 3),
+                        config=ServingConfig(max_batch=3, max_seq=MAX_SEQ))
+    eng.submit(list(range(1, 20)))
+    eng.submit(list(range(30, 45)))
+    L, bk = mcfg.n_layers, block_size(MAX_SEQ, DEFAULT_BK)
+    obs.reset()
+    want = [0, 0]
+    with jax.profiler.trace(str(tmp_path)):
+        for _ in range(3):
+            lengths = [0] * 3
+            for s in eng._slotted.values():
+                lengths[s.slot] = s.prompt_len + len(s.tokens) - 1
+            want[0] += L * blocks_fetched(lengths, MAX_SEQ, DEFAULT_BK)
+            want[1] += L * 3 * MAX_SEQ // bk
+            eng.step()
+    c = obs.summary()["counters"]
+    obs.reset()
+    assert (c["engine.kv_blocks_read"], c["engine.kv_blocks_pool"]) == \
+        tuple(want)
+    assert c["engine.kv_blocks_read"] == 3 * L * 3   # one block a slot
+
+
+def test_latent_kernel_runs_on_a_tpu_without_a_mesh(monkeypatch):
+    """The MLA decode takes the kernel where the pooled steps do, and the
+    XLA einsums under a mesh: the rules put the latent pool's positions on
+    "model", even on a 1x1 mesh."""
+    from repro.dist.hints import sharding_rules
+    from repro.dist.mesh import make_local_mesh
+    mcfg = ref_mod.model_config(small_cfg())
+    assert M._latent_kernel(mcfg) is None                   # the CPU
+    monkeypatch.setattr(M, "_pooled_attention_impl", lambda: "pallas")
+    assert M._latent_kernel(mcfg).keywords == {
+        "softmax_scale": mla_mod.softmax_scale(mcfg), "interpret": False}
+    with sharding_rules(make_local_mesh(1, 1)):
+        assert M._latent_kernel(mcfg) is None
 
 
 def test_published_config_routes_as_deepseek_v3():

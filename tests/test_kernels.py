@@ -9,7 +9,10 @@ from repro.kernels import ref
 from repro.kernels.decode_attention import (block_size, blocks_fetched,
                                             decode_attention)
 from repro.kernels.flash_attention import flash_attention
+from repro.kernels.mla_decode import DEFAULT_BK as MLA_BK
+from repro.kernels.mla_decode import mla_decode_attention
 from repro.models.layers import decode_attention as decode_attention_xla
+from repro.models.mla import _absorbed_xla
 
 RNG = np.random.default_rng(42)
 
@@ -212,3 +215,105 @@ def test_block_size_divides_the_pool():
     assert block_size(4096) == 512 and block_size(4096, 1024) == 1024
     assert block_size(96) == 96 and block_size(300, 64) == 300
     assert block_size(1024, 128) == 128
+
+
+# ------------------------------------------------------------- MLA decode
+MLA_SCALE = 0.1      # about DeepSeek-V3's 192 ** -0.5 times YaRN's mscale^2
+MLA_RAGGED = [0, 1, BK - 1, BK, BK + 1, S_RAGGED]   # empty .. a full pool
+
+
+def mla_inputs(L, B, S, H, C, R, dtype):
+    """q_lat, q_r, the latent and rope pools, and the token's own latent."""
+    return (mk((B, H, C), dtype), mk((B, H, R), dtype),
+            mk((L, B, S, C), dtype), mk((L, B, S, R), dtype),
+            mk((B, C), dtype), mk((B, R), dtype))
+
+
+def mla_xla(q_lat, q_r, c_pool, r_pool, c_new, kr_new, lengths, layer):
+    """The XLA path of ``mla.mla_decode_pooled``, in q_lat's dtype."""
+    return _absorbed_xla(q_lat, q_r, c_pool, r_pool, c_new, kr_new, lengths,
+                         layer, MLA_SCALE).astype(q_lat.dtype)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_mla_decode_matches_xla_path(dtype):
+    """Ragged slots in one call (empty, one latent, either side of a block
+    edge, a full pool), the layer traced as the model's scan traces it."""
+    L, H, C, R = 3, 4, 128, 32
+    args = mla_inputs(L, len(MLA_RAGGED), S_RAGGED, H, C, R, dtype)
+    lengths = jnp.asarray(MLA_RAGGED, jnp.int32)
+    run = jax.jit(lambda li: mla_decode_attention(
+        *args, lengths, li, softmax_scale=MLA_SCALE, block_k=BK,
+        interpret=True))
+    for layer in range(L):
+        out = run(jnp.int32(layer))
+        want = mla_xla(*args, lengths, layer)
+        assert out.shape == want.shape and out.dtype == want.dtype
+        assert np.isfinite(np.asarray(out, np.float32)).all()
+        assert row_rel_err(out, want) < DECODE_TOL[dtype]
+
+
+def test_mla_decode_empty_slot_attends_to_its_own_latent():
+    """A slot of length 0 stays finite: every head's output is the token's
+    own latent, whatever the pools hold."""
+    q_lat, q_r, cp, rp, cn, rn = mla_inputs(1, 2, 64, 4, 128, 32,
+                                            jnp.float32)
+    cp, rp = cp.at[:, 0].set(jnp.nan), rp.at[:, 0].set(jnp.nan)
+    out = mla_decode_attention(q_lat, q_r, cp, rp, cn, rn,
+                               jnp.asarray([0, 17], jnp.int32), 0,
+                               softmax_scale=MLA_SCALE, block_k=16,
+                               interpret=True)
+    np.testing.assert_allclose(np.asarray(out[0]),
+                               np.broadcast_to(np.asarray(cn[0]), (4, 128)),
+                               rtol=1e-6)
+    assert np.isfinite(np.asarray(out)).all()
+
+
+def _mla_fault_readings():
+    """The check's reading (per-row relative error of the MLA kernel
+    against the XLA path) when the XLA path is computed with a planted
+    fault."""
+    lens = [1, BK + 1, 2 * BK + 3, S_RAGGED - 1]
+    args = mla_inputs(1, len(lens), S_RAGGED, 4, 128, 32, jnp.bfloat16)
+    q_lat, q_r, cp, rp, cn, rn = args
+    lengths = jnp.asarray(lens, jnp.int32)
+    out = mla_decode_attention(*args, lengths, 0, softmax_scale=MLA_SCALE,
+                               block_k=BK, interpret=True)
+    good = row_rel_err(out, mla_xla(*args, lengths, 0))
+    full = jnp.full_like(lengths, S_RAGGED)
+    return good, {
+        # every position of the pool attends
+        "lengths ignored": row_rel_err(out, mla_xla(*args, full, 0)),
+        # the last live block is not read
+        "last live block skipped": row_rel_err(out, mla_xla(
+            *args, (lengths - 1) // BK * BK, 0)),
+        # the scores leave out q_r . r
+        "rope part dropped": row_rel_err(out, mla_xla(
+            q_lat, q_r, cp, jnp.zeros_like(rp), cn, jnp.zeros_like(rn),
+            lengths, 0)),
+    }
+
+
+@pytest.mark.parametrize("fault", ["lengths ignored",
+                                   "last live block skipped",
+                                   "rope part dropped"])
+def test_mla_decode_check_sees_planted_faults(fault):
+    good, faults = _mla_fault_readings()
+    assert good < DECODE_TOL[jnp.bfloat16]
+    assert faults[fault] > DECODE_TOL[jnp.bfloat16], faults
+
+
+MLA_S = 8192                          # the deepseek-v3 cell's latent pool
+
+
+@pytest.mark.parametrize("lens,want", [
+    ([0, 0], 2),                      # an empty slot still fetches one block
+    ([1, MLA_BK, MLA_BK + 1], 1 + 1 + 2),
+    ([MLA_S - 1, MLA_S], 2 * MLA_S // MLA_BK),
+    ([1587] * 3, 3 * -(-1587 // MLA_BK)),
+])
+def test_blocks_fetched_counts_live_latent_blocks(lens, want):
+    """The MLA kernel's block size divides the cell's pool, and the count
+    is of those blocks."""
+    assert block_size(MLA_S, MLA_BK) == MLA_BK
+    assert blocks_fetched(lens, MLA_S, MLA_BK) == want

@@ -22,6 +22,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.configs import get_config
 from repro.kernels.decode_attention import decode_attention
 from repro.kernels.flash_attention import flash_attention
+from repro.kernels.mla_decode import mla_decode_attention
 from repro.models import model as M
 
 V5E_HBM_BYTES = 16e9
@@ -96,6 +97,29 @@ def test_decode_attention_compiles_for_v5e(one_chip, no_persistent_cache,
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_mla_decode_attention_compiles_for_v5e(one_chip, no_persistent_cache,
+                                               dtype):
+    """The MLA kernel on the deepseek-v3 cell's latent pools, 9 layers of
+    (32 slots, 8192 positions, 512 + 64), 32 query heads, with the layer
+    traced as the model's scan traces it."""
+    L, B, S, H, C, R = 9, 32, 8192, 32, 512, 64
+    args = (_shape(one_chip, (B, H, C), dtype),
+            _shape(one_chip, (B, H, R), dtype),
+            _shape(one_chip, (L, B, S, C), dtype),
+            _shape(one_chip, (L, B, S, R), dtype),
+            _shape(one_chip, (B, C), dtype),
+            _shape(one_chip, (B, R), dtype),
+            _shape(one_chip, (B,), jnp.int32),
+            _shape(one_chip, (), jnp.int32))
+    compiled = jax.jit(
+        lambda *a: mla_decode_attention(*a, softmax_scale=0.135,
+                                        interpret=False)
+    ).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
 def test_granite_decode_step_fits_one_v5e(one_chip, no_persistent_cache,
                                           monkeypatch):
     """Full-width, full-depth granite-3-2b decode over an 8 x 4096 KV pool —
@@ -131,12 +155,15 @@ def test_granite_decode_step_fits_one_v5e(one_chip, no_persistent_cache,
     assert not re.search(r"= bf16\[40,8,4096,512\]\S* copy\(", txt)
 
 
-def test_deepseek_decode_step_fits_one_v5e(one_chip, no_persistent_cache):
+def test_deepseek_decode_step_fits_one_v5e(one_chip, no_persistent_cache,
+                                           monkeypatch):
     """The benchmark's deepseek-v3-671b share (1 dense + 8 MoE layers at
     published widths, 32 heads and 8 of 256 experts held, 32 x 8192 latent
-    pool) decodes on one chip: the latent pools are read where they lie and
-    written in place, aliased to the donated state, and no op copies a
-    whole pool."""
+    pool) decodes on one chip, as the engine jits it (the MLA kernel, the
+    state donated): the latent pools are read where they lie and written in
+    place, aliased to the donated state, no op copies a whole pool, and no
+    f32 score tensor spans the pool's positions."""
+    monkeypatch.setattr(M, "_pooled_attention_impl", lambda: "pallas")
     import json
     import sys
     from pathlib import Path
@@ -163,8 +190,10 @@ def test_deepseek_decode_step_fits_one_v5e(one_chip, no_persistent_cache):
     assert ma.alias_size_in_bytes >= pools
     assert ma.temp_size_in_bytes < pools // 50
     assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < V5E_HBM_BYTES
-    assert not re.search(r"= bf16\[\d+,32,8192,(512|64)\]\S* copy\(",
-                         compiled.as_text())
+    txt = compiled.as_text()
+    assert not re.search(r"= bf16\[\d+,32,8192,(512|64)\]\S* copy\(", txt)
+    assert "tpu_custom_call" in txt
+    assert not re.search(r"f32\[32,32,8192\]", txt)
 
 
 def test_granite_decode_step_shards_over_v5e_2x2(topo, no_persistent_cache,
