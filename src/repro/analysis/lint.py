@@ -16,8 +16,8 @@ Usage::
         print(f)
 
 Every rule is registered in :data:`RULES` with an id and a default severity.
-Findings can be *suppressed* with a reasoned allow-list entry (same discipline
-as ``benchmarks/trend_allowlist.json``)::
+Findings can be *suppressed* with a reasoned allow-list entry in
+``analysis_allowlist.json``::
 
     [{"rule": "dead-dataset", "target": "random_layered:d*",
       "reason": "random fan-in leaves unsampled layer outputs by design"}]
@@ -595,7 +595,7 @@ def lint_graph(graph: TaskGraph, **kw) -> list[Finding]:
 # -------------------------------------------------------------- suppressions
 def default_allowlist_path() -> str:
     """``analysis_allowlist.json`` at the repo root (three levels above this
-    package), where the benchmarks' trend allow-list convention lives too."""
+    package)."""
     here = os.path.dirname(os.path.abspath(__file__))
     root = os.path.dirname(os.path.dirname(os.path.dirname(here)))
     return os.path.join(root, "analysis_allowlist.json")

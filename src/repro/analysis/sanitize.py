@@ -12,7 +12,8 @@ names the first divergent entry.
 
 Opt-in (the rebuilds are O(cluster) per checkpoint): set ``sanitize=True`` on
 :class:`~repro.core.config.SimConfig` / ``ServingConfig``, or export
-``REPRO_SANITIZE=1`` (``benchmarks/run.py --sanitize`` does exactly that).
+``REPRO_SANITIZE=1`` (the CI ``analysis`` job runs the scheduler-equivalence
+tests so).
 Checkpoint frequency for the simulator is ``SimConfig.sanitize_every`` (every
 N-th event) — the invariants hold at *every* event boundary, the knob only
 trades coverage for speed.

@@ -50,7 +50,7 @@ class SimConfig:
     topology: ClusterTopology | None = None
     # False: the *simulator* still charges real per-link costs but the
     # scheduler/store keep the flat scalar view — the topology-blind
-    # ablation bench_topology compares against.
+    # ablation tests/test_topology.py compares against.
     topology_aware: bool = True
     # Predictive re-replication (health-monitor model): when True, each
     # scheduled failure is flagged ``predict_lead_s`` seconds ahead and the

@@ -492,8 +492,8 @@ class LocationService:
 
     Each shard is an independent dict + lock — the in-process model of one
     metadata server. ``shard_of`` is deterministic so any client can route a
-    lookup without coordination. Counters let the benchmarks report per-shard
-    load balance (the scalability argument for "distributed" in the paper).
+    lookup without coordination. ``load_balance()`` reports per-shard entries
+    and lookups (the scalability argument for "distributed" in the paper).
 
     **Change events.** ``subscribe(fn)`` registers a listener called as
     ``fn(event, key, placement)`` on every metadata change:

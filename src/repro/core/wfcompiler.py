@@ -10,9 +10,9 @@ hints. Output: the same graph with the "rich metadata" the paper describes —
   * topological order, earliest start times, upward ranks (longest path to the
     final task) — the priorities handed to the runtime scheduler.
 
-The hardware model doubles as the roofline calculator used by the benchmarks:
-it knows per-node compute throughput, memory bandwidth, and link bandwidths of
-the target (TPU v5e by default; the paper's HPC-cluster numbers are a config).
+The hardware model knows per-node compute throughput, memory bandwidth, and
+link bandwidths of the target (TPU v5e by default; the paper's HPC-cluster
+numbers are a config).
 """
 
 from __future__ import annotations
@@ -141,7 +141,7 @@ class CompiledWorkflow:
     # task -> est. seconds to stage its still-on-PFS external inputs through
     # the storage hierarchy (remote read + link + top-tier write). The
     # ProactiveScheduler feeds it into preplace to pick the prefetch tier per
-    # dataset (hot inputs -> hbm, bulk -> bb); benchmarks report it.
+    # dataset (hot inputs -> hbm, bulk -> bb).
     est_stage_seconds: dict[str, float] = dataclasses.field(default_factory=dict)
     # external dataset -> est. seconds to stage IT alone into fast memory —
     # the per-dataset term est_stage_seconds sums; the scheduler compares it

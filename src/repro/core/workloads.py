@@ -1,4 +1,4 @@
-"""Canonical scientific-workflow DAGs used by tests and benchmarks.
+"""Canonical scientific-workflow DAGs for the tests, the linter and examples.
 
 ``fig2_workflow`` reproduces the shape of the paper's Fig. 2 example (a Swift/T
 script: two parallel analysis chains over a shared input, merged at the end).
@@ -178,7 +178,7 @@ def pipeline_chain_workflow(n_chains: int = 8, depth: int = 6, *,
     is a *sole copy* on the node that produced it: losing that node before
     the next stage reads it re-runs the producer. The rerun exposure of a
     durability window is therefore proportional to how many stages sit
-    un-flushed when a failure hits — the quantity ``bench_failures`` sweeps.
+    un-flushed when a failure hits — what the durability policies bound.
     A final sink joins the chains (one task; its fan-in is not the point)."""
     C = lambda law: Complexity(law, flops_per_byte=flops_per_byte)  # noqa: E731
     g = TaskGraph()

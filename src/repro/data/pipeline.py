@@ -9,14 +9,14 @@ Two cooperating pieces:
 
 * :class:`PrefetchingLoader` — the paper's proactive pipelining at step grain:
   a background thread *pre-materializes* batch k+1..k+depth and device_puts
-  them (location = the consuming host/device) while step k computes. The
-  train loop's I/O wait is then ~0 (measured in bench_prefetch): exactly the
-  paper's claim, realized with JAX async dispatch instead of Hercules.
+  them (location = the consuming host/device) while step k computes, so the
+  train loop's I/O hides behind the step: the paper's claim, realized with
+  JAX async dispatch instead of Hercules.
 
 The workflow integration (`epoch_workflow`) expresses a training epoch as a
 TaskGraph — load tasks hinted with ``@size``/``@io_ratio``, step tasks with
 ``@compute-complexity`` — so the core scheduler/simulator can reason about a
-REAL workload shape (used by bench_scheduler's "training epoch" scenario).
+REAL workload shape.
 """
 
 from __future__ import annotations

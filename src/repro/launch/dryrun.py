@@ -8,8 +8,8 @@ For each cell this script
      (sharding mismatch, OOM at compile, unsupported collective) is a bug,
   4. records ``compiled.memory_analysis()`` (proves it fits),
      ``cost_analysis()`` (FLOPs/bytes for §Roofline) and the collective
-     traffic parsed from the optimized HLO, into a JSONL file consumed by
-     benchmarks/bench_roofline.py and EXPERIMENTS.md.
+     traffic parsed from the optimized HLO, into a JSONL file, one record
+     per cell.
 
 Usage:
   python -m repro.launch.dryrun --arch granite-3-2b --shape train_4k --mesh pod1

@@ -9,8 +9,8 @@ The cross-layer part (paper → inference): a session's KV cache IS the paper's
 "file". The :class:`Router` records each session's placement in the
 distributed :class:`~repro.core.locstore.LocationService`; follow-up requests
 look the session up and land on the engine/node that holds its cache
-(compute-on-data-path), instead of re-prefilling elsewhere — the measured
-saving is an entire prefill per follow-up turn (see bench_serving).
+(compute-on-data-path), instead of re-prefilling elsewhere — the saving is
+an entire prefill per follow-up turn.
 
 Session caches are first-class replicas in the tiered
 :class:`~repro.core.locstore.LocStore` with their TRUE byte size (the batch-1
@@ -629,7 +629,8 @@ class Router:
     (``locality_evictions``). New sessions go to the least-loaded engine with
     a free slot; when every slot in the cluster is taken, the router parks
     the least-recently-active session somewhere (``allow_park``) instead of
-    raising "engine full". Hit accounting backs bench_serving.
+    raising "engine full". ``locality_hits`` counts the turns that landed on
+    their cache holder.
     """
 
     def __init__(self, engines: list[ServingEngine], store: LocStore, *,

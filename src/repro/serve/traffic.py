@@ -493,8 +493,8 @@ class TraceDriver:
         self._queue: list[float] = []
         self._resume: list[float] = []
         # (effective issue time, ttft seconds) per request, in completion
-        # order — the recovery-window analysis in bench_membership needs the
-        # time series, not just end-of-run percentiles
+        # order — a fail-then-join recovery-window analysis needs the time
+        # series, not just end-of-run percentiles
         self.samples: list[tuple[float, float]] = []
         self._t_end = 0.0
         self.counters: dict[str, float] = {

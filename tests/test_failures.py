@@ -234,6 +234,28 @@ class TestSimulatorFailures:
         assert barrier.fsyncs > 0 and ack.fsyncs > 0
         assert none.fsyncs == 0
 
+    @pytest.mark.parametrize("failures", [(), ((4.0, 0),)],
+                             ids=["f0", "f1"])
+    def test_policy_sweep_keeps_invariants(self, failures):
+        """Every durability policy, with and without a mid-run failure:
+        every task finishes, no object is phantom-durable, no pin leaks.
+        Without failures the policies differ only in fsyncs (the window
+        claims under a failure are test_dirty_loss_reruns_flushed_loss_does_not,
+        on the same workload and schedule)."""
+        wf = _chain_wf()
+        fsyncs = {}
+        for pol in ("none", "fsync_on_barrier", "flush_before_ack"):
+            sim = WorkflowSimulator(wf, ProactiveScheduler(wf), n_nodes=4,
+                                    hw=HPC_CLUSTER, write_policy="back",
+                                    durability=pol, failures=list(failures))
+            r = sim.run()
+            assert r.tasks_done == len(wf.graph.tasks)
+            assert r.phantom_durable == 0
+            assert sim.store.movement_report()["pins"] == 0, "leaked pins"
+            fsyncs[pol] = r.fsyncs
+        if not failures:
+            assert fsyncs["none"] == 0 and fsyncs["fsync_on_barrier"] > 0
+
     def test_failure_cancelled_task_releases_pins(self):
         """Regression (satellite 2): prefetch pins of a task cancelled by the
         failure must be released — task-finish unpin never fires for it."""
@@ -366,6 +388,43 @@ class TestServingFailover:
         assert b.sessions[sid].tokens[:7] == want, \
             "decode after failover must be bit-identical"
         assert store.getxattr(_cache_name(sid), "engine") == 1
+
+    def test_failover_beside_a_live_session(self, setup):
+        """A parked and a live session on the engine that dies: the parked
+        one resumes elsewhere bit-identically without a prefill, the live
+        one is lost."""
+        cfg, params = setup
+        kv = ServingEngine(cfg, params, max_batch=2, max_seq=64).slot_bytes()
+        ctrl = ServingEngine(cfg, params, max_batch=2, max_seq=64, node=0,
+                             store=_failover_store(kv))
+        sid_c = ctrl.submit([5, 6, 7])
+        for _ in range(3):
+            ctrl.step()
+        ctrl.park(sid_c)
+        ctrl.resume(sid_c)
+        for _ in range(3):
+            ctrl.step()
+        want = ctrl.sessions[sid_c].tokens[:7]
+
+        store = _failover_store(kv)
+        a, b = (ServingEngine(cfg, params, max_batch=2, max_seq=64, node=i,
+                              store=store) for i in range(2))
+        router = Router([a, b], store)
+        sid = a.submit([5, 6, 7])
+        for _ in range(3):
+            a.step()
+        a.park(sid)
+        live_sid = a.submit([9, 8, 7])
+        assert store.durable(_cache_name(sid))
+        prefills = a.prefills + b.prefills
+        rep = router.fail_engine(0)
+        assert rep.resumed == (sid,), "the durable parked session fails over"
+        assert rep.lost == (live_sid,), "the live slot's KV died with a"
+        assert a.prefills + b.prefills == prefills, "no re-prefill"
+        for _ in range(3):
+            b.step()
+        assert b.sessions[sid].tokens[:7] == want
+        assert router.failover_resumes >= 1
 
     def test_live_slot_session_is_lost(self, setup):
         cfg, params = setup

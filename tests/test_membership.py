@@ -6,8 +6,9 @@ determinism, the placement-skew bugfix (alive-list remap instead of linear
 probing), risk-aware re-replication ordering toward newcomers, the
 simulator's incremental cached-view absorption, router-level engine joins
 (deferred-slice adoption, zero-re-prefill rebalance with bit-identical
-decode), the ``warm()`` residency-guard parity fix, and a trace-driver
-fail-then-join run recovering pre-failure tail latency.
+decode), the ``warm()`` residency-guard parity fix, trace-driver
+fail-then-join runs recovering pre-failure tail latency, an autoscale join
+under a load spike, and a workflow fail -> rejoin -> growth-join cycle.
 """
 
 import collections
@@ -15,16 +16,18 @@ import collections
 import numpy as np
 import pytest
 
-from repro.core import HPC_CLUSTER
+from repro.core import (HPC_CLUSTER, ProactiveScheduler, StorageHierarchy,
+                        TierSpec, compile_workflow)
 from repro.core.config import ServingConfig
 from repro.core.locstore import (LocStore, SimObject, _stable_hash,
                                  tiered_hierarchy)
 from repro.core.prefetch import PrefetchEngine
-from repro.core.simulator import SimCluster
+from repro.core.simulator import SimCluster, WorkflowSimulator
+from repro.core.workloads import mapreduce_workflow
 from repro.serve.engine import Router, ServingEngine, _cache_name
-from repro.serve.traffic import (MiB, SyntheticBackend, TraceConfig,
-                                 TraceDriver, build_trace_stack,
-                                 generate_trace)
+from repro.serve.traffic import (CostModel, MiB, SyntheticBackend,
+                                 TraceConfig, TraceDriver, build_trace_stack,
+                                 generate_trace, trace_stats)
 
 KV = 4 * MiB
 
@@ -346,11 +349,54 @@ class TestWarmParity:
 
 
 # ----------------------------------------------------------------- driver
+RECOVERY_FACTOR = 1.2       # windowed p95 TTFT back within 1.2x baseline
+
+
+def _window_p95(samples, t_lo, t_hi):
+    """p95 TTFT over samples issued in [t_lo, t_hi); None under 5."""
+    vals = [lat for t, lat in samples if t_lo <= t < t_hi]
+    return float(np.percentile(vals, 95)) if len(vals) >= 5 else None
+
+
+def _within_bar(base, run, t, win):
+    b = _window_p95(base, t, t + win)
+    f = _window_p95(run, t, t + win)
+    if b is None or f is None:
+        return None
+    return f <= RECOVERY_FACTOR * b
+
+
+def _recovery_seconds(base, run, *, t_fail, t_join, win, horizon):
+    """Seconds from the failure to the end of the first two consecutive
+    windows at or after the join whose p95 TTFT is within the bar of the
+    same baseline windows (same trace, so windows align arrival for
+    arrival); None if that never happens within ``horizon``."""
+    t = t_join
+    while t < t_fail + horizon:
+        if all(_within_bar(base, run, t + k * win, win) for k in range(2)):
+            return t + 2 * win - t_fail
+        t += win
+    return None
+
+
+# A bursty trace of 2,500 sessions at 65 requests/s, replayed across four
+# engines whose 64-MiB KV slices are under memory pressure.
+REPLAY = dict(kv=64 * MiB, bb_slots=96)
+
+
+@pytest.fixture(scope="module")
+def replay_trace():
+    return generate_trace(TraceConfig(
+        n_sessions=2_500, followups_per_session=1.2, req_rate=65.0,
+        arrival="bursty", seed=7))
+
+
 class TestTraceFailThenJoin:
-    def _run(self, trace, *, failures=(), joins=()):
+    def _run(self, trace, *, failures=(), joins=(), n_engines=3, kv=KV,
+             bb_slots=64):
         router, store = build_trace_stack(
-            n_engines=3, max_batch=8, kv_bytes=KV, tiered=True,
-            bb_slots_per_node=64, durability="flush_before_ack")
+            n_engines=n_engines, max_batch=8, kv_bytes=kv, tiered=True,
+            bb_slots_per_node=bb_slots, durability="flush_before_ack")
         driver = TraceDriver(router, trace, warm=True, failures=failures,
                              joins=joins)
         return driver.run(), router, driver
@@ -396,3 +442,76 @@ class TestTraceFailThenJoin:
         assert driver.counters["joins"] == 1
         assert router.engines[3].prefills > 0, \
             "the newcomer must actually absorb load"
+
+    def test_fail_then_join_recovers(self, replay_trace):
+        """One of four engines dies halfway through and a replacement joins
+        5 s later. Against the no-failure run: the fleet is whole again,
+        the windowed p95 TTFT recovers, at least 55% of the windows after
+        that stay within the bar (the disruption spans about 40% of this
+        short trace, so its backlog meets the final burst), the overall p99
+        stays within 2x, and joining is no worse than staying at three."""
+        trace, win = replay_trace, 4.0
+        t_fail = trace[len(trace) // 2].t
+        t_join = t_fail + 5.0
+        base, _, base_drv = self._run(trace, n_engines=4, **REPLAY)
+        fo, fo_router, _ = self._run(trace, failures=((t_fail, 0),),
+                                     n_engines=4, **REPLAY)
+        fj, fj_router, fj_drv = self._run(trace, failures=((t_fail, 0),),
+                                          joins=((t_join, 0),), n_engines=4,
+                                          **REPLAY)
+        sb, so, sj = base.summary(), fo.summary(), fj.summary()
+        assert len(fj_router.engines) == 4
+        assert len(fo_router.engines) == 3
+        assert (sj["failover_resumed"] + sj["failover_deferred"]
+                + sj["failover_lost"]) > 0, "the failure never bit"
+        assert sj["joins"] == 1 and sj["engine_full_errors"] == 0
+        duration = trace_stats(trace)["duration"]
+        rec = _recovery_seconds(base_drv.samples, fj_drv.samples,
+                                t_fail=t_fail, t_join=t_join, win=win,
+                                horizon=duration - t_fail)
+        assert rec is not None, "windowed p95 TTFT never recovered"
+        t, good, total = t_fail + rec, 0, 0
+        while t < duration:
+            ok = _within_bar(base_drv.samples, fj_drv.samples, t, win)
+            if ok is not None:
+                total += 1
+                good += ok
+            t += win
+        assert total > 0 and good / total >= 0.55, f"{good}/{total}"
+        # the two runs shed different sessions at the failure: 5% slack
+        assert sj["p99_ttft_ms"] <= 1.05 * so["p99_ttft_ms"]
+        assert sj["p99_ttft_ms"] <= 2.0 * sb["p99_ttft_ms"]
+
+    def test_autoscale_join_beats_no_join(self, replay_trace):
+        """Two engines serve a trace sized for four; the other two join
+        halfway. They take load, and once their parameters are loaded the
+        p95 TTFT beats the same span of a two-engine run with no join."""
+        trace = replay_trace
+        t_spike = trace[len(trace) // 2].t
+        asc, router, asc_drv = self._run(
+            trace, joins=((t_spike, 2), (t_spike, 3)), n_engines=2, **REPLAY)
+        _, _, ctrl_drv = self._run(trace, n_engines=2, **REPLAY)
+        assert len(router.engines) == 4
+        assert asc.summary()["joins"] == 2
+        assert any(router.engines[e].prefills > 0 for e in (2, 3))
+        t_post = t_spike + CostModel().join_params_load_s + 4.0
+        post = [lat for t, lat in asc_drv.samples if t >= t_post]
+        post_ctrl = [lat for t, lat in ctrl_drv.samples if t >= t_post]
+        assert np.percentile(post, 95) < np.percentile(post_ctrl, 95)
+
+
+def test_workflow_membership_cycle_rereplicates_toward_newcomers():
+    """A mapreduce run (12 maps, 6 reducers) on 8 nodes through node 1
+    failing at 4 s, rejoining at 8 s, and node 9 growing the cluster at
+    16 s: both joins land and re-replication is staged toward them."""
+    wf = compile_workflow(mapreduce_workflow(12, 6, 2e9, flops_per_byte=4.0),
+                          HPC_CLUSTER)
+    hier = StorageHierarchy(
+        [TierSpec("hbm", 6e9, 800e9), TierSpec("bb", 12e9, 10e9)],
+        remote=TierSpec("remote", float("inf"), 0.5e9))
+    res = WorkflowSimulator(
+        wf, ProactiveScheduler(wf, risk_aware=True), n_nodes=8,
+        hw=HPC_CLUSTER, failures=[(4.0, 1)], joins=[(8.0, 1), (16.0, 9)],
+        hierarchy=hier, write_policy="back",
+        durability="fsync_on_barrier").run()
+    assert res.joins == 2 and res.rereplications > 0

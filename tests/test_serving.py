@@ -1,6 +1,8 @@
 """Serving engine: continuous batching, slot isolation, location-aware
 routing, and the tiered session lifecycle (KV caches as first-class
-LocStore replicas: submit -> idle-park -> resume-promote -> finish)."""
+LocStore replicas: submit -> idle-park -> resume-promote -> finish), plus the
+memory-pressure sweep where tiered session routing must beat flat pinning
+and the simulator's serving workload where locality keeps KV chains local."""
 
 import dataclasses
 
@@ -10,9 +12,13 @@ import numpy as np
 import pytest
 
 from repro.configs import get_smoke
-from repro.core.locstore import (LocStore, StorageHierarchy, TierSpec,
+from repro.core import (FCFSScheduler, HPC_CLUSTER, LocalityScheduler,
+                        compile_workflow)
+from repro.core.locstore import (GiB, LocStore, StorageHierarchy, TierSpec,
                                  tiered_hierarchy)
 from repro.core.prefetch import PrefetchEngine
+from repro.core.simulator import WorkflowSimulator
+from repro.core.workloads import serving_session_workflow
 from repro.models import decode_step, forward_logits, init_params, prefill
 from repro.serve.engine import (Router, ServingEngine, _cache_name,
                                 _read_slot, _write_slot)
@@ -382,3 +388,108 @@ def test_router_warm_promotes_parked_cache(setup):
     assert router.warmups == 1
     assert not router.warm(99_999)   # unknown session: no-op
     prefetch.shutdown()
+
+
+# ------------------------------------------------------ memory-pressure sweep
+def _pressure_run(cfg, params, n_sess: int, tiered: bool, *, n_engines=2,
+                  max_batch=2, max_seq=64, rounds=2):
+    """More sessions than decode slots, each given ``rounds`` follow-up
+    turns. Flat pinning can only make room by finishing the oldest live
+    session (its cache is lost and its next turn re-prefills the history);
+    tiered routing parks idle sessions and resumes them. Returns (prefills,
+    engine-full errors, store, engines)."""
+    rng = np.random.default_rng(7)
+    if tiered:
+        probe = ServingEngine(cfg, params, max_batch=max_batch,
+                              max_seq=max_seq)
+        kv = probe.slot_bytes()
+        store = LocStore(n_engines, hierarchy=tiered_hierarchy(
+            hbm_bytes=max_batch * kv, host_bytes=max_batch * kv,
+            bb_bytes=4 * GiB), write_policy="back")
+    else:
+        store = LocStore(n_engines)
+    engines = [ServingEngine(cfg, params, max_batch=max_batch,
+                             max_seq=max_seq, node=i, store=store)
+               for i in range(n_engines)]
+    rtr = Router(engines, store, allow_park=tiered)
+    errors = 0
+    # sid -> (engine, history); a dead session keeps its history so that a
+    # follow-up can re-prefill it
+    book: dict[int, tuple[ServingEngine, list[int]]] = {}
+    order: list[int] = []
+
+    def force_finish_lru() -> None:
+        for old in order:
+            if old not in book:         # the session being routed right now
+                continue
+            eng, _ = book[old]
+            s = eng.sessions.get(old)
+            if s is not None and not s.done:
+                eng.finish(old)
+                return
+
+    def admit(prompt: list[int]) -> tuple[ServingEngine, int]:
+        nonlocal errors
+        while True:
+            try:
+                eng = rtr.engine_for()
+                return eng, eng.submit(prompt)
+            except RuntimeError:            # "all engines full"
+                errors += 1
+                force_finish_lru()
+
+    for _ in range(n_sess):
+        prompt = rng.integers(0, cfg.vocab, 8).tolist()
+        eng, sid = admit(prompt)
+        book[sid] = (eng, list(eng.sessions[sid].tokens))
+        order.append(sid)
+    for _ in range(rounds):
+        for i, sid in enumerate(list(order)):
+            eng, hist = book.pop(sid)
+            sess = eng.sessions.get(sid)
+            if tiered:
+                try:
+                    d = rtr.follow_up(sid, hist[-8:])
+                    eng, sid2 = d.engine, d.sid
+                except RuntimeError:
+                    errors += 1
+                    continue
+            elif sess is not None and not sess.done:
+                eng, sid2 = rtr.engine_for(sid), sid    # locality hit
+            else:                                       # cache lost
+                eng, sid2 = admit(hist[-8:])
+            eng.step()
+            book[sid2] = (eng, list(eng.sessions[sid2].tokens))
+            order[i] = sid2
+        if tiered:
+            store.drain_writebacks()            # background flusher tick
+    return sum(e.prefills for e in engines), errors, store, engines
+
+
+@pytest.mark.parametrize("factor", [1.5, 2.0], ids=lambda f: f"x{f}")
+def test_tiered_routing_beats_flat_pinning_under_pressure(setup, factor):
+    """Sessions at 1.5x and 2x the decode slots of two engines: tiered
+    routing takes no engine-full error, re-prefills less than flat pinning,
+    and its store accounts every live session's true KV bytes."""
+    cfg, params = setup
+    n_sess = int(2 * 2 * factor)
+    flat_prefills, _, _, _ = _pressure_run(cfg, params, n_sess, False)
+    prefills, errors, store, engines = _pressure_run(cfg, params, n_sess,
+                                                     True)
+    kv = engines[0].slot_bytes()
+    resident = sum(t["resident_bytes"] for t in store.tier_report().values())
+    live = sum(1 for e in engines for s in e.sessions.values() if not s.done)
+    assert resident >= live * kv * 0.99, \
+        f"tier_report misses KV bytes: {resident} < {live}*{kv}"
+    assert errors == 0, f"tiered routing hit 'engine full' {errors}x"
+    assert prefills < flat_prefills
+
+
+def test_locality_keeps_session_kv_chains_local():
+    """The simulator's serving workload (10 sessions x 3 turns on 4 nodes):
+    the locality scheduler moves fewer KV bytes than FCFS's round-robin."""
+    def moved(sched_cls):
+        wf = compile_workflow(serving_session_workflow(10, 3), HPC_CLUSTER)
+        return WorkflowSimulator(wf, sched_cls(wf), n_nodes=4,
+                                 hw=HPC_CLUSTER).run().bytes_moved
+    assert moved(LocalityScheduler) < moved(FCFSScheduler)

@@ -91,7 +91,7 @@ class TestSimulatorTraceBalances:
         r = sim.run()
         assert r.tasks_done == len(wf.graph.tasks)
         assert_ledger_balances(sim.store)
-        # the SimResult the benchmarks report is the same ledger
+        # the SimResult counters are the same ledger
         rep = sim.store.movement_report()
         assert r.bytes_moved == rep["bytes_moved"]
         assert r.remote_bytes == rep["remote_bytes"]

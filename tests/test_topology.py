@@ -94,16 +94,27 @@ class TestTopologyModel:
 
 
 # --------------------------------------------------------- cross-layer sim
-def aware_vs_blind(aware):
+def aware_vs_blind(aware, topo=None):
+    """The mapreduce shuffle on a 2-rack fabric, 4:1 unless ``topo`` is
+    given; *blind* plans with the flat model while the network charges
+    real paths."""
     wf = compile_workflow(mapreduce_workflow(12, 6, 2e9, flops_per_byte=4.0),
                           HPC_CLUSTER)
-    topo = ClusterTopology.two_tier(2, 4, nic_gbps=1.25e9,
-                                    oversubscription=4.0)
+    if topo is None:
+        topo = ClusterTopology.two_tier(2, 4, nic_gbps=1.25e9,
+                                        oversubscription=4.0)
     sim = WorkflowSimulator(wf, LocalityScheduler(wf, speed_aware=True),
                             n_nodes=8, hw=HPC_CLUSTER, topology=topo,
                             topology_aware=aware, external_loc="scattered",
                             hierarchy=TIGHT, sanitize=True, sanitize_every=1)
-    return sim, sim.run()
+    r = sim.run()
+    assert r.tasks_done == len(wf.graph.tasks)
+    return sim, r
+
+
+MIXED_GEN = [NodeProfile() if i < 4 else
+             NodeProfile(speed=0.6, cls="old-gen", nic_gbps=0.625e9)
+             for i in range(8)]
 
 
 class TestTopologyCharging:
@@ -126,6 +137,24 @@ class TestTopologyCharging:
         _, blind = aware_vs_blind(False)
         assert aware.cross_spine_bytes < blind.cross_spine_bytes
         assert aware.makespan < blind.makespan
+
+    @pytest.mark.parametrize("topo,strict", [
+        (ClusterTopology.two_tier(2, 4, oversubscription=1.0), False),
+        (ClusterTopology.two_tier(2, 4, oversubscription=4.0,
+                                  profiles=MIXED_GEN), True),
+    ], ids=["nonblocking", "mixed-gen"])
+    def test_aware_never_loses_to_blind(self, topo, strict):
+        """On a non-blocking 1:1 spine awareness costs nothing; on a 4:1
+        spine with one rack of old-generation nodes (0.6x compute, half
+        the NIC) it strictly cuts cross-spine bytes and makespan."""
+        _, aware = aware_vs_blind(True, topo)
+        _, blind = aware_vs_blind(False, topo)
+        if strict:
+            assert aware.cross_spine_bytes < blind.cross_spine_bytes
+            assert aware.makespan < blind.makespan
+        else:
+            assert aware.cross_spine_bytes <= blind.cross_spine_bytes
+            assert aware.makespan <= blind.makespan
 
     def test_topology_size_mismatch_is_refused(self):
         wf = compile_workflow(mapreduce_workflow(4, 2), HPC_CLUSTER)
@@ -171,7 +200,9 @@ def predictive_run(predict):
                             failures=[(8.0, 1)], predict_failures=predict,
                             predict_lead_s=3.0, sanitize=True,
                             sanitize_every=1)
-    return sim.run()
+    r = sim.run()
+    assert r.tasks_done == len(wf.graph.tasks)
+    return r
 
 
 class TestPredictiveRereplication:
@@ -255,6 +286,20 @@ class TestTopologyLint:
         relaxed = lint(self.WF, config=cfg,
                        params={"oversub-factor": 1e12})
         assert self.rules(relaxed, "oversubscribed-link") == []
+
+    @pytest.mark.parametrize("oversub,pfs_gbps,flagged", [
+        (8.0, 1e7, True), (1.0, 4e9, False)], ids=["8to1", "1to1"])
+    def test_oversubscribed_link_on_stage_in_plan(self, oversub, pfs_gbps,
+                                                  flagged):
+        """The mapreduce stage-in from a remote PFS trips the rule on an
+        8:1 spine with a starved PFS and stays quiet on a 1:1 fabric."""
+        topo = ClusterTopology.two_tier(2, 4, oversubscription=oversub,
+                                        pfs_gbps=pfs_gbps)
+        wf = compile_workflow(mapreduce_workflow(12, 6, 2e9), HPC_CLUSTER)
+        out = self.rules(
+            lint(wf, config=lint_config(topo, external_loc="remote")),
+            "oversubscribed-link")
+        assert bool(out) == flagged
 
     def test_oversubscribed_link_clean_on_adequate_fabric(self):
         topo = ClusterTopology.two_tier(2, 4, pfs_gbps=2e9)

@@ -4,7 +4,8 @@ Generator: seeded determinism, Zipf-skew and arrival-rate statistical
 sanity, percentile math on known fixtures. Driver: the full
 park/resume/warm/failover lifecycle on the synthetic backend — zero
 engine-full errors under pressure, predictive warming hiding resume
-latency, flat pinning losing on tail TTFT, and bit-identical reruns.
+latency, flat pinning losing on tail TTFT, and bit-identical reruns — on
+small traces, and on a 2.5k-session replay across four engines.
 """
 
 import numpy as np
@@ -149,12 +150,12 @@ def test_route_decision_kinds_synthetic():
 # --------------------------------------------------------------------- driver
 def _run(n_sessions=250, *, warm=False, tiered=True, failures=(), seed=21,
          bb=8, engines=2, batch=4, followups=2.0, rate=60.0,
-         durability="none"):
+         durability="none", kv=8 * MiB):
     trace = generate_trace(TraceConfig(
         n_sessions=n_sessions, followups_per_session=followups,
         req_rate=rate, arrival="bursty", seed=seed))
     router, store = build_trace_stack(n_engines=engines, max_batch=batch,
-                                      kv_bytes=8 * MiB, tiered=tiered,
+                                      kv_bytes=kv, tiered=tiered,
                                       bb_slots_per_node=bb,
                                       durability=durability)
     drv = TraceDriver(router, trace, warm=warm, failures=failures)
@@ -217,6 +218,42 @@ def test_driver_failover_mid_trace():
     assert s["failover_resumed"] + s["failover_lost"] > 0
     assert s["engine_full_errors"] == 0
     assert rep.requests == 600                     # every request was served
+
+
+# ------------------------------------------- 2.5k-session replay, 4 engines
+# One bursty trace at 65 requests/s, sized so that the 64-MiB KV slices of
+# four 8-slot engines are under memory pressure.
+REPLAY = dict(n_sessions=2_500, followups=1.2, rate=65.0, seed=7,
+              engines=4, batch=8, bb=96, kv=64 * MiB)
+
+
+def test_replay_tiered_warm_beats_flat_pinning():
+    """Flat pinning is forced to finish sessions; tiered serving absorbs the
+    pressure by parking, with no engine-full error, and with predictive
+    warming beats flat on p99 TTFT without making p99 resume worse."""
+    sf = _run(tiered=False, **REPLAY)[0].summary()
+    sc = _run(**REPLAY)[0].summary()
+    sw = _run(warm=True, **REPLAY)[0].summary()
+    assert sw["engine_full_errors"] == 0 and sc["engine_full_errors"] == 0
+    assert sw["p99_ttft_ms"] < sf["p99_ttft_ms"]
+    assert sw["warm_hits"] > 0 and sw["resume_hidden_s"] > 0
+    # a partial warm hit pays one extra top-tier read; allow that epsilon
+    assert sw["p99_resume_ms"] <= sc["p99_resume_ms"] * 1.05
+    assert sf["force_finished"] > 0, "flat pinning never hit pressure"
+
+
+def test_replay_failover_rehomes_durable_parks():
+    """An engine node killed halfway through the replay: its durable parked
+    sessions re-home on the three survivors, with no engine-full error."""
+    trace = generate_trace(TraceConfig(
+        n_sessions=2_500, followups_per_session=1.2, req_rate=65.0,
+        arrival="bursty", seed=7))
+    rep, router, _ = _run(warm=True, failures=((trace[len(trace) // 2].t, 0),),
+                          durability="flush_before_ack", **REPLAY)
+    s = rep.summary()
+    assert len(router.engines) == 3
+    assert s["engine_full_errors"] == 0
+    assert s["failover_resumed"] > 0
 
 
 def test_tier_used_matches_tier_report():

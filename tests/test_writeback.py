@@ -27,6 +27,16 @@ def small_hierarchy(cap=100.0):
         remote=TierSpec("remote", float("inf"), 2e9))
 
 
+def pressure_hierarchy(cap):
+    """A quarter of ``cap`` in HBM, ``cap`` of host, 16x in a burst buffer,
+    over a 0.5 GB/s PFS."""
+    return StorageHierarchy(
+        [TierSpec("hbm", cap / 4, 819e9),
+         TierSpec("host", cap, 100e9),
+         TierSpec("bb", 16 * cap, 8e9)],
+        remote=TierSpec("remote", float("inf"), 0.5e9))
+
+
 def tiny_hierarchy(cap=100.0):
     """One node tier: eviction spills straight to the PFS."""
     return StorageHierarchy([TierSpec("hbm", cap, 800e9)],
@@ -106,6 +116,32 @@ class TestWriteBackQueue:
         assert st.remote_bytes == before          # no second PFS write for a
         assert st.exists("a")
 
+    @pytest.mark.parametrize("policy", ["through", "back"])
+    def test_cyclic_reuse_flushes_each_object_once(self, policy):
+        """A working set of 32 64-MiB objects on a node whose tiers hold
+        about 60% of it, re-read and re-staged twice: each object crosses
+        the PFS boundary at most once, and later evictions of PFS-backed
+        replicas are free drops."""
+        n, obj = 32, float(64 << 20)
+        cap = n * obj / 2.0
+        st = LocStore(1, hierarchy=StorageHierarchy(
+            [TierSpec("hbm", cap / 4, 819e9),
+             TierSpec("host", cap / 2, 100e9),
+             TierSpec("bb", cap / 2, 8e9)],
+            remote=TierSpec("remote", float("inf"), 0.5e9)),
+            write_policy=policy)
+        for i in range(n):
+            st.put(f"o{i}", SimObject(obj), loc=0)
+        for _ in range(2):
+            st.drain_writebacks()
+            for i in range(n):
+                st.get(f"o{i}", at=0)
+                st.replicate(f"o{i}", [0])
+        st.drain_writebacks()
+        rep = st.movement_report()
+        assert rep["writebacks"] <= n, "an object was flushed more than once"
+        assert rep["clean_drops"] > 0, "reuse rounds produced no free drops"
+
     def test_writeback_recorded_as_transfer_and_counted(self):
         st = LocStore(1, hierarchy=tiny_hierarchy(100), write_policy="back")
         st.put("a", SimObject(90.0), loc=0)
@@ -159,6 +195,18 @@ class TestWriteAround:
         eng.drain()
         assert eng.skipped_read_once == 1
         assert st.stat("stream").nodes == (REMOTE_TIER,)
+
+    def test_streaming_outputs_do_not_evict_the_hot_set(self):
+        """32 64-MiB streamed outputs written around a node whose host
+        tier is full of a hot working set demote nothing."""
+        n, obj = 32, float(64 << 20)
+        st = LocStore(1, hierarchy=pressure_hierarchy(n * obj / 2.0))
+        for i in range(n):
+            st.put(f"hot{i}", SimObject(obj), loc=0, tier="host")
+        st.reset_accounting()
+        for i in range(n):
+            st.put(f"stream{i}", SimObject(obj), loc=0, mode="around")
+        assert st.movement_report()["demotions"] == 0
 
     def test_store_wide_around_rejected(self):
         with pytest.raises(ValueError):
@@ -250,7 +298,8 @@ class TestCoordinatedEviction:
 class TestDoNotEvictPins:
     """PR 4 satellite: prefetched replicas are pinned do-not-evict for their
     consumer's lifetime, so coordinated eviction at comfortable capacity
-    cannot undo prefetch work (the bench_writeback 1 GiB regression)."""
+    cannot undo prefetch work (a regression once seen on montage at 1 GiB,
+    which ``TestSimulatorPlumbing.test_policy_sweep`` covers)."""
 
     def test_pinned_replica_survives_eviction_pressure(self):
         st = LocStore(2, hierarchy=tiny_hierarchy(100),
@@ -345,20 +394,44 @@ class TestDoNotEvictPins:
 
 
 class TestSimulatorPlumbing:
-    def _hier(self, cap):
-        return StorageHierarchy(
-            [TierSpec("hbm", cap / 4, 819e9),
-             TierSpec("host", cap, 100e9),
-             TierSpec("bb", 16 * cap, 8e9)],
-            remote=TierSpec("remote", float("inf"), 0.5e9))
+    @pytest.mark.parametrize("width,cap_gb", [(16, 0.125), (16, 0.25),
+                                              (24, 1.0)])
+    def test_policy_sweep(self, width, cap_gb):
+        """Montage under write-through, write-back and write-back with
+        coordinated eviction. At every point: no sole copy dropped, every
+        dataset resolvable, every task done, every prefetch pin released.
+        At the tight points (up to 0.25 GiB) write-back flushes and cuts
+        io-wait; at comfortable capacity (1 GiB) the pins shield
+        prefetched replicas from coordinated eviction."""
+        wf = compile_workflow(montage_workflow(width), HPC_CLUSTER)
+        results = {}
+        for label, kw in (("through", {}),
+                          ("back", {"write_policy": "back"}),
+                          ("back_coord", {"write_policy": "back",
+                                          "coordinated_eviction": True})):
+            sim = WorkflowSimulator(wf, ProactiveScheduler(wf), n_nodes=4,
+                                    hw=HPC_CLUSTER,
+                                    hierarchy=pressure_hierarchy(cap_gb * GB), **kw)
+            r = results[label] = sim.run()
+            assert sim.store.coordination_violations == 0
+            assert r.tasks_done == len(wf.graph.tasks)
+            for name in sim.store.loc.names():
+                assert sim.store.exists(name)
+            assert sim.store.movement_report()["pins"] == 0
+        if cap_gb <= 0.25:
+            assert results["back"].writebacks > 0
+            assert (results["back"].io_wait_total
+                    < results["through"].io_wait_total)
+        if cap_gb >= 1.0:
+            assert results["back_coord"].pin_protected_evictions > 0
 
     def test_writeback_reduces_io_wait_under_pressure(self):
         wf = compile_workflow(montage_workflow(16), HPC_CLUSTER)
-        hier = self._hier(0.125 * GB)
+        hier = pressure_hierarchy(0.125 * GB)
         r_thru = WorkflowSimulator(wf, LocalityScheduler(wf), n_nodes=4,
                                    hw=HPC_CLUSTER, hierarchy=hier).run()
         r_back = WorkflowSimulator(wf, LocalityScheduler(wf), n_nodes=4,
-                                   hw=HPC_CLUSTER, hierarchy=self._hier(0.125 * GB),
+                                   hw=HPC_CLUSTER, hierarchy=pressure_hierarchy(0.125 * GB),
                                    write_policy="back").run()
         assert r_back.writebacks > 0
         assert r_back.io_wait_total < r_thru.io_wait_total
@@ -367,7 +440,7 @@ class TestSimulatorPlumbing:
     def test_coordinated_eviction_sim_never_loses_data(self):
         wf = compile_workflow(montage_workflow(16), HPC_CLUSTER)
         sim = WorkflowSimulator(wf, ProactiveScheduler(wf), n_nodes=4,
-                                hw=HPC_CLUSTER, hierarchy=self._hier(0.25 * GB),
+                                hw=HPC_CLUSTER, hierarchy=pressure_hierarchy(0.25 * GB),
                                 write_policy="back", coordinated_eviction=True)
         r = sim.run()
         assert r.tasks_done == len(wf.graph.tasks)
@@ -377,7 +450,7 @@ class TestSimulatorPlumbing:
     def test_queue_drained_by_end_of_run(self):
         wf = compile_workflow(montage_workflow(12), HPC_CLUSTER)
         sim = WorkflowSimulator(wf, LocalityScheduler(wf), n_nodes=4,
-                                hw=HPC_CLUSTER, hierarchy=self._hier(0.125 * GB),
+                                hw=HPC_CLUSTER, hierarchy=pressure_hierarchy(0.125 * GB),
                                 write_policy="back")
         sim.run()
         assert len(sim.store.writeback) == 0
